@@ -140,7 +140,8 @@ def bench_chaos():
             DPPFConfig(engine="flat", overlap="staleness_k", staleness=K,
                        elastic=True, tau=TAU),
             base_lr=0.1, total_steps=STEPS).rounds),
-        tau=TAU, work_s_per_step=2e-3, gather_bytes=1e6, R=M, staleness=K,
+        tau=TAU, work_s_per_step=2e-3, gather_bytes=1e6,
+        device_kind="TPU v5 lite", R=M, staleness=K,
         degraded_rounds=c.get("degrade", 0),
         retried_rounds=c.get("retry", 0),
         restores=c.get("restore", 0), restore_bytes=float(restore_bytes),

@@ -44,6 +44,8 @@ MAX_SLOTS = 4
 CHUNK = 8
 
 ROOFLINE_ARCHS = ("gemma2-2b", "dbrx-132b", "zamba2-7b")
+# the chip whose published peaks price the roofline rows
+MODELLED_KIND = "TPU v5 lite"
 ROOFLINE_SHAPE = {"max_slots": 64, "chunk": 256, "buf_len": 8192}
 
 
@@ -130,7 +132,8 @@ def bench_roofline():
         sb = measured_state_bytes(cfg, ROOFLINE_SHAPE["buf_len"])
         r = serving_model(cfg, max_slots=ROOFLINE_SHAPE["max_slots"],
                           chunk=ROOFLINE_SHAPE["chunk"],
-                          state_bytes_per_slot=sb)
+                          state_bytes_per_slot=sb,
+                          device_kind=MODELLED_KIND)
         rows[arch] = {
             "state_bytes_per_slot": int(sb),
             "decode_bound": r["decode_bound"],

@@ -33,6 +33,10 @@ from repro.train import (
 )
 from repro.train.trainer import TrainState
 
+# the chip whose published peaks price the modelled round times
+# (launch.roofline.PEAKS); the measured columns are this host's
+MODELLED_KIND = "TPU v5 lite"
+
 
 def _time(fn, *args, n=20):
     fn(*args)  # compile
@@ -312,8 +316,10 @@ def bench_overlap_round(*, smoke=False):
         fwd = 2 * bs * sum(a * b for a, b in zip(dims, dims[1:]))
         data_bytes = R * (n // cols_sz) * 4 + R * R * 4
         return rf.probe_round_model(
-            work_s_per_step=3 * fwd * (M // rows_sz) / rf.PEAK_FLOPS,
-            tau=tau, gather_bytes=data_bytes, R=R, mode=mode,
+            work_s_per_step=3 * fwd * (M // rows_sz)
+            / rf.peaks(MODELLED_KIND)["flops"],
+            tau=tau, gather_bytes=data_bytes, device_kind=MODELLED_KIND,
+            R=R, mode=mode,
             staleness=k if mode == "staleness_k" else 1) * 1e6
 
     K_DEPTH = 2
@@ -380,7 +386,6 @@ def bench_ring_round(*, smoke=False):
             note="needs 8 devices; set "
                  "XLA_FLAGS=--xla_force_host_platform_device_count=8")
         return None
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_flat_engine_mesh, ring_gather
     R = 8
@@ -397,12 +402,12 @@ def bench_ring_round(*, smoke=False):
     def _gather(v):
         return jax.lax.all_gather(v, ("data",), axis=0, tiled=True)
 
-    f_ring = jax.jit(shard_map(_ring, mesh=mesh, in_specs=P("data", None),
-                               out_specs=P(None, None), check_rep=False))
-    f_gather = jax.jit(shard_map(_gather, mesh=mesh,
+    f_ring = jax.jit(jax.shard_map(_ring, mesh=mesh, in_specs=P("data", None),
+                               out_specs=P(None, None), check_vma=False))
+    f_gather = jax.jit(jax.shard_map(_gather, mesh=mesh,
                                  in_specs=P("data", None),
                                  out_specs=P(None, None),
-                                 check_rep=False))
+                                 check_vma=False))
     same = bool(jnp.array_equal(f_ring(x), f_gather(x)))
     us_ring = _time(f_ring, x, n=n_it)
     us_gather = _time(f_gather, x, n=n_it)
@@ -523,9 +528,10 @@ def bench_autotune(*, smoke=False):
         dims = [data["dim"], width, width, data["n_classes"]]
         fwd = 2 * cand.batch * sum(a * b for a, b in zip(dims, dims[1:]))
         return rf.probe_round_model(
-            work_s_per_step=3 * fwd * M / rf.PEAK_FLOPS, tau=cand.tau,
-            gather_bytes=M * n * 4 + M * M * 4, R=M,
-            mode="doublebuf") * 1e6
+            work_s_per_step=3 * fwd * M / rf.peaks(MODELLED_KIND)["flops"],
+            tau=cand.tau,
+            gather_bytes=M * n * 4 + M * M * 4, device_kind=MODELLED_KIND,
+            R=M, mode="doublebuf") * 1e6
 
     space = TuneSpace(min_batch=2, max_batch=32, taus=(2, 4),
                       chunks=(1, 2), probe_budget=16, overlap="doublebuf")

@@ -7,6 +7,7 @@ from repro.configs.base import (
     InputShape,
     MeshPlan,
     ModelConfig,
+    cut,
     reduced,
 )
 
@@ -59,6 +60,7 @@ __all__ = [
     "InputShape",
     "MeshPlan",
     "ModelConfig",
+    "cut",
     "get_arch",
     "get_shape",
     "reduced",

@@ -196,6 +196,32 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     return dataclasses.replace(cfg, **changes)
 
 
+def cut(cfg: ModelConfig, *, layers: int = 0, vocab: int = 0) -> ModelConfig:
+    """One chip's share of a PUBLISHED config: the first ``layers`` layers
+    and ``vocab`` vocabulary rows (0 keeps the published value). Widths
+    (d_model, heads, d_ff, experts) are never touched. A cut must keep
+    whole periods of the layer pattern and at least 1/8 of the published
+    vocabulary (the share one chip of an 8-way vocabulary split holds);
+    anything else raises ``ValueError``."""
+    changes = {}
+    if layers:
+        period = len(cfg.layer_pattern)
+        if not 0 < layers <= cfg.n_layers or layers % period:
+            raise ValueError(
+                f"{cfg.name}: --layers {layers} must be a multiple of the "
+                f"{period}-layer pattern period in [1, {cfg.n_layers}]")
+        changes["n_layers"] = layers
+    if vocab:
+        floor = -(-cfg.vocab_size // 8)
+        if not floor <= vocab <= cfg.vocab_size:
+            raise ValueError(
+                f"{cfg.name}: --vocab {vocab} must be in [{floor}, "
+                f"{cfg.vocab_size}] (at least 1/8 of the published "
+                "vocabulary)")
+        changes["vocab_size"] = vocab
+    return dataclasses.replace(cfg, **changes)
+
+
 # ---------------------------------------------------------------------------
 # Input shapes (assigned)
 # ---------------------------------------------------------------------------

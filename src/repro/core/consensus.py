@@ -39,6 +39,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import methods as _methods
 from repro.core import pullpush as pp
@@ -60,7 +61,7 @@ def init_state(method, stacked, *, engine=None):
     if engine is not None:
         if spec.filter_mu:
             L = engine.layout
-            return {"g_ema": jnp.zeros((L.M, L.n), jnp.float32)}
+            return {"g_ema": jnp.zeros((L.M, L.width), jnp.float32)}
         return {}
     if spec.center_beta:
         return {"center": pp.tree_mean0(stacked)}
@@ -239,7 +240,7 @@ def lower_stages(engine, dcfg, lam_t, *, losses=None, grad_norms=None,
     push = dcfg.push and spec.pushes
     L = engine.layout
     M, R = L.M, L.R
-    eye = jnp.eye(R, dtype=jnp.float32)
+    eye = np.eye(R, dtype=np.float32)        # host constants: R is static
     u = engine.uniform                       # (R,) worker mean weights
     zeros = jnp.zeros((R,), jnp.float32)
     act = gate = None

@@ -51,6 +51,21 @@ This engine keeps ONE persistent flat view for the whole training run:
   ``precise=True`` selects exact gap-space stages instead (one extra
   (R, n) buffer per round) for bit-level parity at every scale.
 
+  Kernel vs precise: both exact modes differ only in fp32 rounding order.
+  The kernel adds the Gram one column block at a time, so with
+  ``rho = (n / block_cols + 64) * eps32`` a stage's ``r_i`` agree to
+  relative ``rho``, and each output element to
+  ``|c1_i| / r_i * 2 rho * |x - tx| + 4 R eps32 (1 + |1 - coef_i|)
+  (|x| + |tx|)`` (the coefficient's error times the gap, plus the
+  rounding of the mix; ``tx = T x``). ``chip_smoke.py`` checks the
+  compiled kernel against this on the chip. Every (R, n) contraction asks
+  for ``Precision.HIGHEST``: a TPU's default f32 matmul would round the
+  view to bf16.
+
+  On the kernel path the view's columns are padded once, at ``flatten``,
+  to a whole number of kernel blocks (``FlatLayout.width``); zero columns
+  are inert in every stage and ``unflatten`` drops them.
+
 Method semantics (incl. push-from-recomputed-center ordering) mirror
 ``repro.core.consensus.apply_round``'s tree path, which remains the parity
 oracle. See DESIGN.md §Consensus-engine.
@@ -71,6 +86,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -105,6 +121,10 @@ class FlatLayout:
     offsets: Tuple[int, ...]
     n: int            # parameters per worker
     M: int            # workers
+    # columns of the view: n, padded on the kernel path to a whole number
+    # of kernel column blocks (zero columns are inert in every stage), so
+    # the round never pads or copies the view; unflatten drops them
+    width: int
     aux: int = 0      # extra state rows (easgd center)
 
     @property
@@ -123,13 +143,28 @@ class FlatLayout:
 # at every scale.
 GRAM_NOISE_FACTOR = 256.0
 _EPS32 = float(jnp.finfo(jnp.float32).eps)
+# the view's (R, n) contractions in full fp32: a TPU's default f32 matmul
+# rounds operands to bf16 (on the CPU this is the default anyway)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
+def _eye(R):
+    """(R, R) identity as a host constant. R is static, so the small
+    (R, R) matrices are literals rather than traced iotas (the TPU compiler
+    aborts on a tiny iota fused into an elementwise op, e.g. at R = 2)."""
+    return np.eye(R, dtype=np.float32)
 
 
 @dataclass(frozen=True)
 class ConsensusEngine:
     layout: FlatLayout
     use_kernel: bool = False      # Pallas fused_round vs jnp Gram+GEMM
-    interpret: bool = True        # Pallas interpret mode (CPU)
+    interpret: Optional[bool] = None  # Pallas interpret mode; None = off
+                                      # on a TPU, on everywhere else
     precise: bool = False         # jnp path: exact gap-space stages
     block_cols: int = 2048
     eps: float = 1e-12
@@ -157,31 +192,38 @@ class ConsensusEngine:
         # the fused kernel is TPU-targeted: compile it there, interpret it
         # when explicitly requested elsewhere (tests); CPU/GPU default to
         # the jnp Gram+GEMM path
-        backend = jax.default_backend()
         if "use_kernel" not in kw:
-            kw["use_kernel"] = backend == "tpu"
-        if "interpret" not in kw:
-            kw["interpret"] = backend != "tpu"
+            kw["use_kernel"] = jax.default_backend() == "tpu"
+        width = o
+        if kw["use_kernel"]:
+            from repro.kernels.pullpush import pullpush as pk
+            width = pk.padded_width(o, kw.get("block_cols", cls.block_cols))
         layout = FlatLayout(treedef=treedef, shapes=shapes, dtypes=dtypes,
-                            offsets=tuple(offsets), n=o, M=M, aux=aux)
+                            offsets=tuple(offsets), n=o, M=M, width=width,
+                            aux=aux)
         return cls(layout=layout, **kw)
 
     # -- flat view management (flatten happens ONCE per training run) -------
 
     def flatten(self, stacked):
-        """Stacked pytree -> (R, n) fp32. Aux rows are initialized here
-        (easgd/parle: elastic center = worker mean)."""
+        """Stacked pytree -> (R, width) fp32. Aux rows are initialized here
+        (easgd/parle: elastic center = worker mean); padding columns are
+        zero."""
         leaves = jax.tree_util.tree_leaves(stacked)
-        M = self.layout.M
-        flat = jnp.concatenate(
-            [l.reshape(M, -1).astype(jnp.float32) for l in leaves], axis=1)
+        L = self.layout
+        M = L.M
+        cols = [l.reshape(M, -1).astype(jnp.float32) for l in leaves]
+        if L.width > L.n:
+            cols.append(jnp.zeros((M, L.width - L.n), jnp.float32))
+        flat = jnp.concatenate(cols, axis=1)
         if self.layout.aux:
             flat = jnp.concatenate(
                 [flat, jnp.mean(flat, axis=0, keepdims=True)], axis=0)
         return flat
 
     def unflatten(self, flat):
-        """Worker rows of the flat view -> stacked pytree (original dtypes)."""
+        """Worker rows of the flat view -> stacked pytree (original dtypes).
+        Padding columns past ``layout.n`` are dropped here."""
         L = self.layout
         rows = flat[:L.M]
         out = [rows[:, off:off + math.prod(shape)]
@@ -212,9 +254,12 @@ class ConsensusEngine:
 
     @property
     def uniform(self):
-        """(R,) uniform weights over worker rows (zeros on aux rows)."""
+        """(R,) uniform weights over worker rows (zeros on aux rows), a
+        host constant like ``_eye``."""
         L = self.layout
-        return jnp.zeros((L.R,), jnp.float32).at[:L.M].set(1.0 / L.M)
+        u = np.zeros((L.R,), np.float32)
+        u[:L.M] = 1.0 / L.M
+        return u
 
     def _colsum(self, partial):
         """Complete a column-dimension contraction. Single-shard: identity.
@@ -231,18 +276,18 @@ class ConsensusEngine:
         GRAM_NOISE_FACTOR and the module docstring). Sharded: per-shard
         partial Gram psum'd over the column axes."""
         f = flat.astype(jnp.float32)
-        return self._colsum(f @ f.T)
+        return self._colsum(_mm(f, f.T))
 
     @staticmethod
     def sq_forms(G, V):
         """r2_i = V_i^T G V_i for each row of V. For an uncentered or
         block-centered Gram the rows must sum to 0 (shift invariance); for
         a gap Gram any V is valid."""
-        return jnp.maximum(jnp.sum((V @ G) * V, axis=1), 0.0)
+        return jnp.maximum(jnp.sum(_mm(V, G) * V, axis=1), 0.0)
 
     def mix(self, flat, W):
         """x <- W @ x (one GEMM over the flat view)."""
-        return W.astype(jnp.float32) @ flat
+        return _mm(W.astype(jnp.float32), flat)
 
     def stage_comm(self, chunk, T):
         """The stage-1 column contraction over a COLUMN CHUNK of the flat
@@ -263,9 +308,9 @@ class ConsensusEngine:
             return self._colsum(pk.partial_gram(
                 f, block_cols=self.block_cols, interpret=self.interpret))
         if self.precise:
-            g = T.astype(jnp.float32) @ f - f
-            return self._colsum(g @ g.T)
-        return self._colsum(f @ f.T)
+            g = _mm(T.astype(jnp.float32), f) - f
+            return self._colsum(_mm(g, g.T))
+        return self._colsum(_mm(f, f.T))
 
     def _gap_stage(self, flat, T, c0, c1, *, gram=None):
         """Exact (``precise=True``) stage: materialize the targets
@@ -282,26 +327,26 @@ class ConsensusEngine:
         one weight vector w, so d_m = x_m - mean = (e_m - u)^T g.
         """
         R, M = self.layout.R, self.layout.M
-        eye = jnp.eye(R, dtype=jnp.float32)
+        eye = _eye(R)
         u = self.uniform
         # T @ x then subtract — NOT (T - I) @ x: the row-stochastic dot is
         # clean (collapsed identical rows reproduce exactly, e.g. after a
         # hard pull) and the subtraction of nearby values is exact, so a
         # degenerate gap is a true zero, matching the tree path's d = x - a
-        tx = T @ flat
+        tx = _mm(T, flat)
         Gg = gram
         if Gg is None:
             g = tx - flat
-            Gg = self._colsum(g @ g.T)
-        r = jnp.sqrt(jnp.maximum(jnp.diagonal(Gg), 0.0))
+            Gg = self._colsum(_mm(g, g.T))
+        r = jnp.sqrt(jnp.maximum(jnp.sum(Gg * eye, axis=1), 0.0))
         coef = c0 + c1 / jnp.maximum(r, self.eps)
         new = tx + (1.0 - coef)[:, None] * (flat - tx)
         # d_m = (u - e_m)^T g;  new_m - mean(new) = ((coef_m - 1) e_m
         #   + u * (1 - coef))^T g  — both exact forms over the gap Gram
-        V_pre = jnp.broadcast_to(u, (R, R)) - eye
+        V_pre = np.broadcast_to(u, (R, R)) - eye
         pre = jnp.mean(jnp.sqrt(self.sq_forms(Gg, V_pre)[:M]))
-        V_post = jnp.diag(coef - 1.0) + jnp.broadcast_to(u * (1.0 - coef),
-                                                         (R, R))
+        V_post = eye * (coef - 1.0)[:, None] \
+            + jnp.broadcast_to(u * (1.0 - coef), (R, R))
         post = jnp.mean(jnp.sqrt(self.sq_forms(Gg, V_post)[:M]))
         return new, r, pre, post
 
@@ -326,9 +371,8 @@ class ConsensusEngine:
         mid-scan (DESIGN.md §Overlap).
         """
         R, M = self.layout.R, self.layout.M
-        eye = jnp.eye(R, dtype=jnp.float32)
-        u = self.uniform
-        Vu = eye - jnp.broadcast_to(u, (R, R))
+        eye = _eye(R)
+        Vu = eye - np.broadcast_to(self.uniform, (R, R))
 
         if self.use_kernel:
             from repro.kernels.pullpush import pullpush as pk
@@ -351,7 +395,7 @@ class ConsensusEngine:
             coef = c0 + c1 / jnp.maximum(r, self.eps)
             W = eye + coef[:, None] * (T - eye)
             pre = jnp.mean(jnp.sqrt(self.sq_forms(G, Vu)[:M]))
-            post = jnp.mean(jnp.sqrt(self.sq_forms(G, Vu @ W)[:M]))
+            post = jnp.mean(jnp.sqrt(self.sq_forms(G, _mm(Vu, W))[:M]))
             return new, r, pre, post
 
         if self.precise:
@@ -359,12 +403,12 @@ class ConsensusEngine:
 
         G = self.gram(flat) if gram is None else gram
         # the floor guards coef only — metrics report the (clamped) forms
-        floor = GRAM_NOISE_FACTOR * _EPS32 * jnp.max(jnp.diagonal(G))
+        floor = GRAM_NOISE_FACTOR * _EPS32 * jnp.max(G * eye)
         r = jnp.sqrt(jnp.maximum(self.sq_forms(G, eye - T), floor))
         coef = c0 + c1 / jnp.maximum(r, self.eps)
         W = eye + coef[:, None] * (T - eye)
         pre = jnp.mean(jnp.sqrt(self.sq_forms(G, Vu)[:M]))
-        post = jnp.mean(jnp.sqrt(self.sq_forms(G, Vu @ W)[:M]))
+        post = jnp.mean(jnp.sqrt(self.sq_forms(G, _mm(Vu, W))[:M]))
         return self.mix(flat, W), r, pre, post
 
     def exact_stage(self, flat, lam_r):
@@ -374,14 +418,14 @@ class ConsensusEngine:
         Returns ``(new_flat, r, pre_dist, post_dist)``.
         """
         R, M = self.layout.R, self.layout.M
-        eye = jnp.eye(R, dtype=jnp.float32)
+        eye = _eye(R)
         u = self.uniform
-        T = jnp.broadcast_to(u, (R, R))
+        T = np.broadcast_to(u, (R, R))
         if self.layout.aux:
-            T = jnp.concatenate([T[:M], eye[M:]], axis=0)
-        g = T @ flat - flat                       # worker rows: mean - x_m
-        Gg = self._colsum(g @ g.T)
-        r = jnp.sqrt(jnp.maximum(jnp.diagonal(Gg), 0.0))
+            T = np.concatenate([T[:M], eye[M:]], axis=0)
+        g = _mm(T, flat) - flat                   # worker rows: mean - x_m
+        Gg = self._colsum(_mm(g, g.T))
+        r = jnp.sqrt(jnp.maximum(jnp.sum(Gg * eye, axis=1), 0.0))
         inv = 1.0 / jnp.maximum(r, self.eps)
         units = -g[:M] * inv[:M, None]            # (x_m - mean)/r_m
         mean_unit = jnp.mean(units, axis=0, keepdims=True)
@@ -391,8 +435,8 @@ class ConsensusEngine:
         # so new_m - mean(new) = (-(1 + (lam_r/M) inv_m) e_m
         #   + (lam_r/M)(u * inv))^T g — an exact form over the gap Gram.
         pre = jnp.mean(r[:M])
-        iv = jnp.where(jnp.arange(R) < M, inv, 0.0)
-        V_post = (-jnp.diag(1.0 + (lam_r / M) * iv)
+        iv = jnp.where(np.arange(R) < M, inv, 0.0)
+        V_post = (-eye * (1.0 + (lam_r / M) * iv)[:, None]
                   + (lam_r / M) * jnp.broadcast_to(u * iv, (R, R)))
         post = jnp.mean(jnp.sqrt(self.sq_forms(Gg, V_post)[:M]))
         return new, r, pre, post

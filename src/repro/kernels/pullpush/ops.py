@@ -23,7 +23,7 @@ from repro.core.engine import ConsensusEngine
 
 @functools.partial(jax.jit,
                    static_argnames=("eps", "interpret", "use_kernel"))
-def pullpush_fused(stacked, alpha, lam, *, eps=1e-12, interpret=True,
+def pullpush_fused(stacked, alpha, lam, *, eps=1e-12, interpret=None,
                    use_kernel=True):
     """Eq. 5 over a worker-stacked pytree via the consensus engine.
     Returns (new_stacked, per-worker distances).

@@ -17,7 +17,14 @@ kernels live here (DESIGN.md §Consensus-engine):
   ``sq_dist`` + ``apply_update`` pair and their duplicated padding logic.
 
 Block shape (rows, LANE)/(rows, block_cols) keeps the working set in VMEM
-and the lane dimension hardware-aligned.
+and the lane dimension hardware-aligned. The engine kernels take the worker
+count R itself as the row block (a block equal to the full dimension is
+always legal), so no row is ever padded; columns are padded only when the
+caller's width does not tile into blocks — the engine pads its persistent
+view once (``core.engine.FlatLayout.width``), so the round never copies.
+
+``interpret=None`` resolves to ``jax.default_backend() != "tpu"``: the
+kernels compile on a TPU and are interpreted everywhere else.
 """
 from __future__ import annotations
 
@@ -25,20 +32,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 BLOCK_ROWS = 256  # 256*128*4B*2 tensors = 256 KiB of VMEM per step
-SUBLANE = 8       # fp32 sublane quantum: row counts are padded to this
+# fp32 matmuls in full precision: Mosaic's default contraction may round
+# the operands to bf16, which would round every parameter each round
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _round_up(x, m):
     return -(-x // m) * m
 
 
+def _interpret(interpret):
+    """``None`` -> interpret everywhere but on a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
 # ---------------------------------------------------------------------------
-# Shared padding helpers (used by every kernel below)
+# Padding helpers of the reference pair
 # ---------------------------------------------------------------------------
 
 def _pad_view(x):
@@ -53,9 +70,8 @@ def _pad_view(x):
 def _pad_grid(views, block_rows=BLOCK_ROWS):
     """Pad (rows, LANE) views to a whole number of row blocks.
 
-    Returns (padded_views, grid) — the single source of the grid/padding
-    arithmetic that used to be copied between ``sq_dist`` and
-    ``apply_update``.
+    Returns (padded_views, grid) — the grid/padding arithmetic shared by
+    ``sq_dist`` and ``apply_update``.
     """
     rows = views[0].shape[0]
     grid = _round_up(rows, block_rows) // block_rows
@@ -87,7 +103,7 @@ def _apply_kernel(coef_ref, x_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sq_dist(x, a, *, interpret=True):
+def sq_dist(x, a, *, interpret=None):
     """||x - a||^2 via the blockwise reduction kernel. x, a: (n,)."""
     xv, _ = _pad_view(x)
     av, _ = _pad_view(a)
@@ -101,13 +117,13 @@ def sq_dist(x, a, *, interpret=True):
         ],
         out_specs=pl.BlockSpec((1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(xv, av)
     return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def apply_update(x, a, coef, *, interpret=True):
+def apply_update(x, a, coef, *, interpret=None):
     """out = x + (a - x) * coef in one fused pass. x, a: (n,)."""
     xv, n = _pad_view(x)
     av, _ = _pad_view(a)
@@ -123,7 +139,7 @@ def apply_update(x, a, coef, *, interpret=True):
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xv.shape, x.dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(coef, xv, av)
     return out.reshape(-1)[:n]
 
@@ -137,6 +153,35 @@ def _eye(n, dtype=jnp.float32):
     r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     return (r == c).astype(dtype)
+
+
+def _col_block(n, block_cols):
+    """Column block for an operand of width ``n``: ``n`` itself when it
+    fits one block (a full-dimension block is always legal), else
+    ``block_cols`` rounded to the lane width."""
+    bc = _round_up(block_cols, LANE)
+    return n if n <= bc else bc
+
+
+def padded_width(n, block_cols=2048):
+    """The width an (R, n) operand tiles into blocks at: the engine pads
+    its persistent view to this once, so no kernel call pads or copies."""
+    return _round_up(n, _col_block(n, block_cols))
+
+
+def _pad_cols(flat, width):
+    """(R, n) -> fp32 (R, width) with zero columns. A zero column is inert:
+    it adds nothing to any Gram and mixes to zero. Only callers whose
+    width does not tile into blocks pay this copy (column shards and
+    overlap chunks); the engine's persistent view is padded once."""
+    f = flat.astype(jnp.float32)
+    n = f.shape[1]
+    return jnp.pad(f, ((0, 0), (0, width - n))) if width > n else f
+
+
+def _row_vec(c, R):
+    """Scalar or (R,) coefficients -> (R, 1) fp32."""
+    return jnp.broadcast_to(jnp.asarray(c, jnp.float32), (R,)).reshape(R, 1)
 
 
 def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
@@ -156,8 +201,11 @@ def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
         # cancellation of an uncentered x @ x.T — entries are O(spread^2),
         # not O(||x||^2). Any zero-sum quadratic form of G is exact.
         e = x - x[0:1, :]
-        g_acc[...] += jnp.dot(e, e.T, preferred_element_type=jnp.float32)
-        o_ref[...] = x  # placeholder; phase 1 overwrites every block
+        g_acc[...] += jnp.dot(e, e.T, precision=_HIGHEST,
+                              preferred_element_type=jnp.float32)
+        # o_ref is not written: its block index stays (0, 0) through
+        # phase 0 and phase 1's first step, so nothing is written back
+        # until phase 1 has filled block 0
 
     @pl.when((phase == 1) & (j == 0))
     def _coef():
@@ -166,7 +214,8 @@ def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
         R = G.shape[0]
         eye = _eye(R)
         # r^2_i = (e_i - T_i)^T G (e_i - T_i), vectorized over rows.
-        tg = jnp.dot(T, G, preferred_element_type=jnp.float32)
+        tg = jnp.dot(T, G, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
         diag_g = jnp.sum(G * eye, axis=1, keepdims=True)
         diag_tg = jnp.sum(T * G, axis=1, keepdims=True)       # G symmetric
         diag_tgt = jnp.sum(tg * T, axis=1, keepdims=True)
@@ -185,14 +234,15 @@ def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
         # single W @ x GEMM whose rounding grows with |c| * ||x||
         x = x_ref[...]
         c = coef_scr[...]
-        tx = jnp.dot(t_ref[...], x, preferred_element_type=jnp.float32)
+        tx = jnp.dot(t_ref[...], x, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
         o_ref[...] = tx + (1.0 - c) * (x - tx)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_cols", "interpret"))
 def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
-                interpret=True):
+                interpret=None):
     """One consensus stage over the flat (R, n) worker matrix, fused.
 
     Per row i: ``r_i = ||x_i - T_i @ x||``, ``coef_i = c0_i + c1_i /
@@ -203,47 +253,45 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
 
     Single ``pallas_call``, grid (2, n_blocks): phase 0 accumulates the
     Gram (one HBM read of x), phase 1 applies the mixing (one more read +
-    the only write). Returns ``(out (R, n) f32, r (R,), G (R, R))`` — G is
-    the *block-centered* Gram: only zero-sum quadratic forms of it are
+    the only write). The row block is R itself; the output aliases the
+    input, so under a donated caller the stage updates the view in place.
+    Returns ``(out (R, n) f32, r (R,), G (R, R))`` — G is the
+    *block-centered* Gram: only zero-sum quadratic forms of it are
     meaningful (see repro/core/engine.py).
     """
     R, n = flat.shape
-    Rp = _round_up(max(R, SUBLANE), SUBLANE)
-    bc = min(block_cols, _round_up(n, LANE))
-    nb = _round_up(n, bc) // bc
-    # pad rows: identity target + zero coefs => rows (and G forms) inert
-    xp, tp = _pad_flat(flat, Rp, bc, nb), _pad_target(T, Rp)
-    c0p = jnp.zeros((Rp, 1), jnp.float32).at[:R, 0].set(
-        jnp.broadcast_to(jnp.asarray(c0, jnp.float32), (R,)))
-    c1p = jnp.zeros((Rp, 1), jnp.float32).at[:R, 0].set(
-        jnp.broadcast_to(jnp.asarray(c1, jnp.float32), (R,)))
-
+    bc = _col_block(n, block_cols)
+    width = padded_width(n, block_cols)
     out, r, G = pl.pallas_call(
         functools.partial(_fused_round_kernel, eps=eps),
-        grid=(2, nb),
+        grid=(2, width // bc),
         in_specs=[
-            pl.BlockSpec((Rp, bc), lambda p, j: (0, j)),
-            pl.BlockSpec((Rp, Rp), lambda p, j: (0, 0)),
-            pl.BlockSpec((Rp, 1), lambda p, j: (0, 0)),
-            pl.BlockSpec((Rp, 1), lambda p, j: (0, 0)),
+            pl.BlockSpec((R, bc), lambda p, j: (0, j)),
+            pl.BlockSpec((R, R), lambda p, j: (0, 0)),
+            pl.BlockSpec((R, 1), lambda p, j: (0, 0)),
+            pl.BlockSpec((R, 1), lambda p, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((Rp, bc), lambda p, j: (0, j)),
-            pl.BlockSpec((Rp, 1), lambda p, j: (0, 0)),
-            pl.BlockSpec((Rp, Rp), lambda p, j: (0, 0)),
+            # phase 0 parks on block 0 (never written there); phase 1
+            # walks the blocks
+            pl.BlockSpec((R, bc), lambda p, j: (0, j * p)),
+            pl.BlockSpec((R, 1), lambda p, j: (0, 0)),
+            pl.BlockSpec((R, R), lambda p, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Rp, nb * bc), jnp.float32),
-            jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((Rp, Rp), jnp.float32),
+            jax.ShapeDtypeStruct((R, width), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, R), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((Rp, Rp), jnp.float32),   # Gram accumulator
-            pltpu.VMEM((Rp, 1), jnp.float32),    # per-row coefficients
+            pltpu.VMEM((R, R), jnp.float32),   # Gram accumulator
+            pltpu.VMEM((R, 1), jnp.float32),   # per-row coefficients
         ],
-        interpret=interpret,
-    )(xp, tp, c0p, c1p)
-    return out[:R, :n], r[:R, 0], G[:R, :R]
+        input_output_aliases={0: 0},
+        interpret=_interpret(interpret),
+    )(_pad_cols(flat, width), T.astype(jnp.float32), _row_vec(c0, R),
+      _row_vec(c1, R))
+    return (out if width == n else out[:, :n]), r[:, 0], G
 
 
 # ---------------------------------------------------------------------------
@@ -269,81 +317,75 @@ def _partial_gram_kernel(x_ref, g_ref):
 
     x = x_ref[...]
     e = x - x[0:1, :]                      # block-centered (see fused_round)
-    g_ref[...] += jnp.dot(e, e.T, preferred_element_type=jnp.float32)
+    g_ref[...] += jnp.dot(e, e.T, precision=_HIGHEST,
+                          preferred_element_type=jnp.float32)
 
 
 def _mix_kernel(c_ref, x_ref, t_ref, o_ref):
     x = x_ref[...]
-    tx = jnp.dot(t_ref[...], x, preferred_element_type=jnp.float32)
+    tx = jnp.dot(t_ref[...], x, precision=_HIGHEST,
+                 preferred_element_type=jnp.float32)
     o_ref[...] = tx + (1.0 - c_ref[...]) * (x - tx)
 
 
-def _pad_flat(flat, Rp, bc, nb):
-    """(R, n) -> zero-padded (Rp, nb*bc) fp32 — the one copy of the flat
-    matrix padding, shared by ``fused_round`` and both phase kernels."""
-    R, n = flat.shape
-    return jnp.pad(flat.astype(jnp.float32), ((0, Rp - R), (0, nb * bc - n)))
-
-
-def _pad_target(T, Rp):
-    """(R, R) -> (Rp, Rp) with IDENTITY pad rows, so padding stays inert in
-    both the Gram forms and the mixing (shared by the same callers)."""
-    R = T.shape[0]
-    tp = jnp.zeros((Rp, Rp), jnp.float32).at[:R, :R].set(
-        T.astype(jnp.float32))
-    return tp + jnp.diag((jnp.arange(Rp) >= R).astype(jnp.float32))
-
-
 @functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
-def partial_gram(flat, *, block_cols=2048, interpret=True):
+def partial_gram(flat, *, block_cols=2048, interpret=None):
     """Block-centered Gram of a (R, n_local) column shard — phase 0 of
     ``fused_round`` as its own kernel. Zero-sum quadratic forms of the
     summed per-shard outputs equal those of the full-width Gram."""
     R, n = flat.shape
-    Rp = _round_up(max(R, SUBLANE), SUBLANE)
-    bc = min(block_cols, _round_up(n, LANE))
-    nb = _round_up(n, bc) // bc
-    xp = _pad_flat(flat, Rp, bc, nb)
-    G = pl.pallas_call(
+    bc = _col_block(n, block_cols)
+    width = padded_width(n, block_cols)
+    return pl.pallas_call(
         _partial_gram_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((Rp, bc), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((Rp, Rp), lambda j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Rp, Rp), jnp.float32),
-        interpret=interpret,
-    )(xp)
-    return G[:R, :R]
+        grid=(width // bc,),
+        in_specs=[pl.BlockSpec((R, bc), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((R, R), lambda j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, R), jnp.float32),
+        interpret=_interpret(interpret),
+    )(_pad_cols(flat, width))
 
 
 @functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
-def mix_shard(flat, T, coef, *, block_cols=2048, interpret=True):
+def mix_shard(flat, T, coef, *, block_cols=2048, interpret=None):
     """Apply ``out_i = x_i + coef_i (T_i x - x_i)`` to a (R, n_local)
     column shard with PRECOMPUTED coefficients — phase 1 of ``fused_round``
-    (same uniform gap form, exact at c = 1 and for huge |c|)."""
+    (same uniform gap form, exact at c = 1 and for huge |c|). The output
+    aliases the input, like ``fused_round``."""
     R, n = flat.shape
-    Rp = _round_up(max(R, SUBLANE), SUBLANE)
-    bc = min(block_cols, _round_up(n, LANE))
-    nb = _round_up(n, bc) // bc
-    xp, tp = _pad_flat(flat, Rp, bc, nb), _pad_target(T, Rp)
-    cp = jnp.zeros((Rp, 1), jnp.float32).at[:R, 0].set(
-        jnp.broadcast_to(jnp.asarray(coef, jnp.float32), (R,)))
+    bc = _col_block(n, block_cols)
+    width = padded_width(n, block_cols)
     out = pl.pallas_call(
         _mix_kernel,
-        grid=(nb,),
+        grid=(width // bc,),
         in_specs=[
-            pl.BlockSpec((Rp, 1), lambda j: (0, 0)),
-            pl.BlockSpec((Rp, bc), lambda j: (0, j)),
-            pl.BlockSpec((Rp, Rp), lambda j: (0, 0)),
+            pl.BlockSpec((R, 1), lambda j: (0, 0)),
+            pl.BlockSpec((R, bc), lambda j: (0, j)),
+            pl.BlockSpec((R, R), lambda j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((Rp, bc), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((Rp, nb * bc), jnp.float32),
-        interpret=interpret,
-    )(cp, xp, tp)
-    return out[:R, :n]
+        out_specs=pl.BlockSpec((R, bc), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((R, width), jnp.float32),
+        input_output_aliases={1: 0},
+        interpret=_interpret(interpret),
+    )(_row_vec(coef, R), _pad_cols(flat, width), T.astype(jnp.float32))
+    return out if width == n else out[:, :n]
+
+
+def _coef_from_gram(T, c0, c1, G, eps):
+    """(r, coef) of one stage from a completed Gram (zero-sum forms)."""
+    R = T.shape[0]
+    # a host-constant identity: as a traced iota the TPU compiler aborts
+    # on the tiny (R, R) subtraction (seen at R = 2)
+    V = np.eye(R, dtype=np.float32) - T.astype(jnp.float32)
+    r = jnp.sqrt(jnp.maximum(jnp.sum(
+        jnp.matmul(V, G, precision=_HIGHEST) * V, axis=1), 0.0))
+    coef = (jnp.broadcast_to(jnp.asarray(c0, jnp.float32), (R,))
+            + jnp.asarray(c1, jnp.float32) / jnp.maximum(r, eps))
+    return r, coef
 
 
 def mix_from_gram(flat, T, c0, c1, G, *, eps=1e-12, block_cols=2048,
-                  interpret=True):
+                  interpret=None):
     """Gather-free mixing epilogue: one consensus stage whose column
     contraction ALREADY happened — ``G`` is a completed (block-centered or
     plain) Gram, e.g. the psum'd sum of per-chunk ``partial_gram`` calls
@@ -354,36 +396,28 @@ def mix_from_gram(flat, T, c0, c1, G, *, eps=1e-12, block_cols=2048,
     left at the round boundary. Returns ``(out, r, G)`` like
     ``fused_round``.
     """
-    R = flat.shape[0]
-    V = jnp.eye(R, dtype=jnp.float32) - T.astype(jnp.float32)
-    r = jnp.sqrt(jnp.maximum(jnp.sum((V @ G) * V, axis=1), 0.0))
-    coef = (jnp.broadcast_to(jnp.asarray(c0, jnp.float32), (R,))
-            + jnp.asarray(c1, jnp.float32) / jnp.maximum(r, eps))
+    r, coef = _coef_from_gram(T, c0, c1, G, eps)
     out = mix_shard(flat, T, coef, block_cols=block_cols,
                     interpret=interpret)
     return out, r, G
 
 
 def fused_round_sharded(flat, T, c0, c1, *, axis, eps=1e-12,
-                        block_cols=2048, interpret=True):
+                        block_cols=2048, interpret=None):
     """``fused_round`` for a column shard under shard_map.
 
     ``flat`` is the local (R, n_local) shard; ``axis`` names the mesh
     axis/axes the columns are sharded over. Runs the partial-Gram kernel,
     completes the Gram with ``lax.psum(G, axis)`` (the round's only
-    engine-level collective — (R, R) bytes), derives r/coef at trace level,
-    and applies the mixing kernel shard-locally. Returns ``(out, r, G)``
-    with the same meaning as ``fused_round`` (G is the global
+    engine-level collective — (R, R) bytes), derives r/coef at trace
+    level, and applies the mixing kernel shard-locally. Returns ``(out, r,
+    G)`` with the same meaning as ``fused_round`` (G is the global
     block-centered Gram: zero-sum forms only). Must be called inside a
     ``shard_map`` that binds ``axis``.
     """
-    R = flat.shape[0]
     G = partial_gram(flat, block_cols=block_cols, interpret=interpret)
     G = jax.lax.psum(G, axis)
-    V = jnp.eye(R, dtype=jnp.float32) - T.astype(jnp.float32)
-    r = jnp.sqrt(jnp.maximum(jnp.sum((V @ G) * V, axis=1), 0.0))
-    coef = (jnp.broadcast_to(jnp.asarray(c0, jnp.float32), (R,))
-            + jnp.asarray(c1, jnp.float32) / jnp.maximum(r, eps))
+    r, coef = _coef_from_gram(T, c0, c1, G, eps)
     out = mix_shard(flat, T, coef, block_cols=block_cols,
                     interpret=interpret)
     return out, r, G

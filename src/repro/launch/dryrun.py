@@ -49,6 +49,8 @@ from repro.train.trainer import TrainState
 # the LR/step budget every train-mode dry-run compiles against (and the
 # clock the report's round-plan table renders)
 TRAIN_LR, TRAIN_STEPS = 0.1, 1000
+# the chip whose published peaks price the dry-run's roofline terms
+MODELLED_KIND = "TPU v5 lite"
 
 
 def _sds(tree_specs, tree_shardings):
@@ -235,7 +237,7 @@ def run_one(arch, shape_name, mesh_kind, *, mode=None, plan_name="baseline",
     else:
         fn, args, cfg = build_decode(arch, shape, mesh, plan, plan_name)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -270,7 +272,7 @@ def run_one(arch, shape_name, mesh_kind, *, mode=None, plan_name="baseline",
     coll = ana["collectives"]
     scale = 1.0 / tau if mode == "train" else 1.0
     terms = rf.roofline(ana["flops"], ana["bytes"], coll,
-                        seconds_scale=scale)
+                        device_kind=MODELLED_KIND, seconds_scale=scale)
     mf = rf.model_flops(cfg, shape, mode=mode)
     chips = int(mesh.devices.size)
 
@@ -296,7 +298,7 @@ def run_one(arch, shape_name, mesh_kind, *, mode=None, plan_name="baseline",
         # (launch.roofline.overlap_model) — rendered by roofline_report.py
         # and the EXPERIMENTS.md §Overlap-roofline table
         rec["overlap_model"] = rf.overlap_model(
-            terms, ana["collective_axis_bytes"],
+            terms, ana["collective_axis_bytes"], device_kind=MODELLED_KIND,
             R=_n_workers(mesh, plan), seconds_scale=scale)
         rec["staleness"] = staleness if overlap == "staleness_k" else None
     os.makedirs(out_dir, exist_ok=True)

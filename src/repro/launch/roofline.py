@@ -1,7 +1,7 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Hardware model (TPU v5e target):
-  peak 197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+Hardware model: the published peaks of the chip named by ``device_kind``
+(``PEAKS``); every modelled caller names the kind it models.
 
 XLA's ``cost_analysis`` counts while-loop (scan) bodies ONCE, which
 undercounts layer-stacked models by ~L*tau (verified: gemma2 raw HLO flops
@@ -20,9 +20,22 @@ from __future__ import annotations
 import re
 from collections import defaultdict
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of HBM
+# bandwidth, 1,600 Gbit/s of chip-to-chip interconnect (4 links, ~50 GB/s
+# each, the per-link figure the collective terms use).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of one chip kind; a kind not in ``PEAKS`` is an error,
+    never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -275,21 +288,24 @@ def analyze_hlo(text, n_model=16):
             "collective_axis_bytes": axis_bytes}
 
 
-def roofline(flops, bytes_accessed, coll, *, seconds_scale=1.0):
-    """Three roofline terms in seconds (optionally scaled, e.g. 1/tau to
-    amortize a fused round over its local steps)."""
+def roofline(flops, bytes_accessed, coll, *, device_kind,
+             seconds_scale=1.0):
+    """Three roofline terms in seconds on ``device_kind`` (optionally
+    scaled, e.g. 1/tau to amortize a fused round over its local steps)."""
+    pk = peaks(device_kind)
     total_coll = sum(v["bytes"] for v in coll.values())
     terms = {
-        "compute_s": flops / PEAK_FLOPS * seconds_scale,
-        "memory_s": bytes_accessed / HBM_BW * seconds_scale,
-        "collective_s": total_coll / ICI_BW * seconds_scale,
+        "compute_s": flops / pk["flops"] * seconds_scale,
+        "memory_s": bytes_accessed / pk["hbm_bw"] * seconds_scale,
+        "collective_s": total_coll / pk["ici_bw"] * seconds_scale,
     }
     terms["bottleneck"] = max(
         [k for k in terms if k.endswith("_s")], key=lambda k: terms[k])
     return terms
 
 
-def overlap_model(terms, axis_bytes, *, R=8, seconds_scale=1.0):
+def overlap_model(terms, axis_bytes, *, device_kind, R=8,
+                  seconds_scale=1.0):
     """Modeled round time per overlap mode against the comm/compute
     crossover (DESIGN.md §Overlap).
 
@@ -330,13 +346,14 @@ def overlap_model(terms, axis_bytes, *, R=8, seconds_scale=1.0):
     By construction ``staleness_k_s[k] <= doublebuf_s <= staleness1_s <=
     exact_s`` (check_bench pins the ordering on the committed records).
     """
+    ici_bw = peaks(device_kind)["ici_bw"]
     work = terms["compute_s"] + terms["memory_s"]
-    model_s = axis_bytes.get("model", 0.0) / ICI_BW * seconds_scale
+    model_s = axis_bytes.get("model", 0.0) / ici_bw * seconds_scale
     gather_bytes = (axis_bytes.get("data", 0.0)
                     + axis_bytes.get("mixed", 0.0)
                     + axis_bytes.get("unknown", 0.0))
-    data_s = gather_bytes / ICI_BW * seconds_scale
-    psum_s = min(R * R * 4 / ICI_BW * seconds_scale, data_s)
+    data_s = gather_bytes / ici_bw * seconds_scale
+    psum_s = min(R * R * 4 / ici_bw * seconds_scale, data_s)
     ring_s = data_s * (R - 1) / max(R, 1)
     rows = {
         "exact_s": work + model_s + data_s,
@@ -358,8 +375,8 @@ def overlap_model(terms, axis_bytes, *, R=8, seconds_scale=1.0):
 
 
 def probe_round_model(*, work_s_per_step: float, tau: int,
-                      gather_bytes: float, R: int = 8, mode: str = "none",
-                      staleness: int = 1) -> float:
+                      gather_bytes: float, device_kind: str, R: int = 8,
+                      mode: str = "none", staleness: int = 1) -> float:
     """One overlap mode's modeled round seconds for an autotune probe
     (``train/autotune.py``): tau local steps of ``work_s_per_step``
     against a ``gather_bytes`` worker-axis consensus payload, routed
@@ -375,7 +392,7 @@ def probe_round_model(*, work_s_per_step: float, tau: int,
         raise ValueError(f"staleness must be >= 1, got {staleness}")
     rows = overlap_model(
         {"compute_s": work_s_per_step * tau, "memory_s": 0.0},
-        {"data": float(gather_bytes)}, R=R)
+        {"data": float(gather_bytes)}, device_kind=device_kind, R=R)
     if mode == "none":
         return rows["exact_s"]
     if mode == "staleness1":
@@ -426,7 +443,8 @@ def model_flops(cfg, shape, *, mode: str) -> float:
 
 
 def serving_model(cfg, *, max_slots: int, chunk: int,
-                  state_bytes_per_slot: float, dtype_bytes: int = 2):
+                  state_bytes_per_slot: float, device_kind: str,
+                  dtype_bytes: int = 2):
     """Prefill-vs-decode roofline for the continuous-batching engine
     (DESIGN.md §Serving).
 
@@ -447,20 +465,23 @@ def serving_model(cfg, *, max_slots: int, chunk: int,
         raise ValueError(f"max_slots must be >= 1, got {max_slots}")
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
+    pk = peaks(device_kind)
+    peak_flops, hbm_bw = pk["flops"], pk["hbm_bw"]
     n_act = cfg.active_param_count()
     param_bytes = cfg.param_count() * dtype_bytes
 
-    dec_compute = 2.0 * n_act * max_slots / PEAK_FLOPS
-    dec_memory = (param_bytes + 2.0 * max_slots * state_bytes_per_slot) / HBM_BW
+    dec_compute = 2.0 * n_act * max_slots / peak_flops
+    dec_memory = (param_bytes
+                  + 2.0 * max_slots * state_bytes_per_slot) / hbm_bw
     decode_s = max(dec_compute, dec_memory)
 
-    pre_compute = 2.0 * n_act * chunk / PEAK_FLOPS
-    pre_memory = (param_bytes + 2.0 * state_bytes_per_slot) / HBM_BW
+    pre_compute = 2.0 * n_act * chunk / peak_flops
+    pre_memory = (param_bytes + 2.0 * state_bytes_per_slot) / hbm_bw
     prefill_s = max(pre_compute, pre_memory)
 
     # slots needed before a decode step stops being a parameter stream
-    denom = 2.0 * n_act / PEAK_FLOPS - 2.0 * state_bytes_per_slot / HBM_BW
-    crossover = (param_bytes / HBM_BW) / denom if denom > 0 else float("inf")
+    denom = 2.0 * n_act / peak_flops - 2.0 * state_bytes_per_slot / hbm_bw
+    crossover = (param_bytes / hbm_bw) / denom if denom > 0 else float("inf")
 
     return {
         "params_bytes": float(param_bytes),
@@ -480,7 +501,8 @@ DISK_BW = 1.2e9  # checkpoint restore stream (NVMe-class sequential read)
 
 
 def supervisor_model(*, rounds: int, tau: int, work_s_per_step: float,
-                     gather_bytes: float, R: int = 8, staleness: int = 1,
+                     gather_bytes: float, device_kind: str, R: int = 8,
+                     staleness: int = 1,
                      degraded_rounds: int = 0, retried_rounds: int = 0,
                      restores: int = 0, restore_bytes: float = 0.0,
                      backoff_s: float = 0.0):
@@ -521,8 +543,8 @@ def supervisor_model(*, rounds: int, tau: int, work_s_per_step: float,
             "must be >= 0")
     round_s = probe_round_model(
         work_s_per_step=work_s_per_step, tau=tau,
-        gather_bytes=gather_bytes, R=R, mode="staleness_k",
-        staleness=staleness)
+        gather_bytes=gather_bytes, device_kind=device_kind, R=R,
+        mode="staleness_k", staleness=staleness)
     local_s = work_s_per_step * tau
     fault_free_s = rounds * round_s
     degraded_saved_s = degraded_rounds * (round_s - local_s)

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.checkpoint import load_pytree
 from repro.configs import ARCHS, get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import GREEDY, Request, SamplingParams, SlotEngine, serve
 
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

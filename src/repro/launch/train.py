@@ -5,6 +5,13 @@ same script runs under the production mesh with the dry-run's shardings.
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
       --workers 4 --tau 4 --alpha 0.1 --lam 0.5 --steps 200
+
+Without ``--smoke`` the published config runs at its published widths;
+``--layers`` and ``--vocab`` cut it to one chip's share
+(``configs.cut``), e.g. yi-6b on one TPU v5e:
+
+  PYTHONPATH=src python -m repro.launch.train --arch yi-6b --layers 1 \
+      --vocab 8000 --workers 4 --tau 2 --seq 2048 --batch 1 --steps 8
 """
 from __future__ import annotations
 
@@ -12,14 +19,17 @@ import argparse
 import os
 import tempfile
 import time
+from dataclasses import dataclass, field
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import load_train_state, save_pytree, save_train_state
-from repro.configs import ARCHS, DPPFConfig, get_arch, reduced
+from repro.configs import ARCHS, DPPFConfig, cut, get_arch, reduced
 from repro.core import methods as method_registry
 from repro.data import TokenTask, make_lm_batch, make_round_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import make_optimizer
 from repro.train import (
@@ -31,15 +41,30 @@ from repro.train.clock import RoundMetricsLogger
 from repro.train.trainer import TrainState, average_params
 
 
-def main(argv=None):
+@dataclass
+class TrainRun:
+    """What ``main`` returns: the held-out eval loss, the final train
+    state, and each round's wall seconds (ending in ``block_until_ready``;
+    the first includes its compile)."""
+    eval_loss: float
+    state: Any = None
+    round_s: list = field(default_factory=list)
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-runnable)")
     ap.add_argument("--d-model", type=int, default=0,
                     help="override d_model of the smoke config (e.g. scale "
-                         "toward ~100M params)")
-    ap.add_argument("--layers", type=int, default=0)
+                         "toward ~100M params); --smoke only")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the first N layers (whole layer-pattern "
+                         "periods) of the config")
+    ap.add_argument("--vocab", type=int, default=0,
+                    help="keep V vocabulary rows (at least 1/8 of the "
+                         "published vocabulary without --smoke)")
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--tau", type=int, default=4)
     ap.add_argument("--alpha", type=float, default=0.1)
@@ -281,7 +306,7 @@ def main(argv=None):
                  "consensus method (a local-only method never syncs, so "
                  "there is nothing to degrade or rejoin)")
 
-    cfg = get_arch(args.arch)
+    published = cfg = get_arch(args.arch)
     if args.smoke:
         over = {}
         if args.d_model:
@@ -290,10 +315,25 @@ def main(argv=None):
                         d_ff=2 * args.d_model if cfg.d_ff else 0)
         if args.layers:
             over["n_layers"] = args.layers
+        if args.vocab:
+            over["vocab_size"] = args.vocab
         cfg = reduced(cfg, **over)
+    else:
+        if args.d_model:
+            ap.error("--d-model changes a width; without --smoke the "
+                     "published widths are kept")
+        try:
+            cfg = cut(cfg, layers=args.layers, vocab=args.vocab)
+        except ValueError as e:
+            ap.error(str(e))
+    enable_compile_cache()
     model = build_model(cfg)
     n_params = sum(l.size for l in jax.tree.leaves(
         jax.eval_shape(model.init, jax.random.PRNGKey(0))))
+    if not args.smoke:
+        print(f"cut: arch={cfg.name} layers={cfg.n_layers}/"
+              f"{published.n_layers} vocab={cfg.vocab_size}/"
+              f"{published.vocab_size} n={n_params} workers={args.workers}")
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M workers={args.workers} "
           f"tau={args.tau} alpha={args.alpha} lam={args.lam}")
 
@@ -330,9 +370,12 @@ def main(argv=None):
             base_lr=args.lr, total_steps=args.steps, seed=args.seed)
         if args.tune_oom_above:
             runner = inject_oom_above(runner, args.tune_oom_above)
+        # the probe ranking's model is priced on the chip this repo
+        # targets (launch.roofline.PEAKS); the measured probes rescale it
         model_fn = make_lm_model_fn(n_params=n_params, seq=args.seq,
                                     workers=args.workers,
                                     overlap=args.overlap,
+                                    device_kind="TPU v5 lite",
                                     staleness=args.staleness)
         tune_plan = tune(runner, model_fn, space)
         ch = tune_plan.chosen
@@ -473,7 +516,8 @@ def main(argv=None):
                       f"tau {spec.tau:3d}) "
                       f"loss {float(m['train_loss']):.4f} "
                       f"consensus_dist {float(m['consensus_dist']):.3f} "
-                      f"lam_t {float(m.get('lam_t', 0)):.3f}")
+                      f"lam_t {float(m.get('lam_t', 0)):.3f} "
+                      f"wall {sup.round_wall_s[-1]:.3f}s")
 
         sup = Supervisor(clock, workers=args.workers, membership=membership,
                          quorum=args.quorum, retry_budget=args.retry_budget,
@@ -511,7 +555,9 @@ def main(argv=None):
     if args.ckpt:
         save_pytree(args.ckpt, final, extra={"steps": args.steps})
         print(f"checkpoint -> {args.ckpt}")
-    return float(loss)
+    return TrainRun(eval_loss=float(loss), state=state,
+                    round_s=list(sup.round_wall_s) if mspec.communicates
+                    else [])
 
 
 if __name__ == "__main__":

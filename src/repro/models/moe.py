@@ -18,19 +18,13 @@ def _constrain(x, spec_axes):
     (if one is active) so GSPMD keeps them expert-sharded instead of
     all-reducing the full (T, E, C) tensor across the TP group — found to be
     the dominant collective in the train_4k dry-run (§Perf iteration 2).
-    No-op on meshes without a 'model' axis (CPU tests)."""
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty or "model" not in mesh.axis_names:
-            return x
-        from jax.sharding import PartitionSpec
-        return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec_axes))
-    except Exception:
+    No-op unless ``jax.set_mesh`` has set a mesh with a 'model' axis (CPU
+    tests)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
+    from jax.sharding import PartitionSpec
+    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec_axes))
 
 
 def init_moe(key, cfg, dtype):

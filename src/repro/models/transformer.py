@@ -100,24 +100,18 @@ def _seq_constrain(x, cfg):
     """Sequence-parallel activations (§Perf seqshard plan): pin the residual
     stream's sequence dim to the model axis between blocks, so norms and
     element-wise ops run on S/TP tokens and the TP all-reduces lower to
-    reduce-scatter + all-gather pairs. No-op without an ambient model axis."""
+    reduce-scatter + all-gather pairs. No-op unless ``jax.set_mesh`` has
+    set a mesh with a model axis."""
     if not cfg.seq_shard_acts or x.ndim != 3:
         return x
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty or "model" not in mesh.axis_names:
-            return x
-        if x.shape[1] % mesh.shape["model"]:
-            return x
-        from jax.sharding import PartitionSpec
-        return jax.lax.with_sharding_constraint(
-            x, PartitionSpec(None, "model", None))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()     # the one set by jax.set_mesh
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
+    if x.shape[1] % mesh.shape["model"]:
+        return x
+    from jax.sharding import PartitionSpec
+    return jax.lax.with_sharding_constraint(
+        x, PartitionSpec(None, "model", None))
 
 
 def _apply_block(kind, p, x, cfg, window, state, index):

@@ -417,18 +417,19 @@ def make_round_probe_runner(init_fn, loss_fn, opt, dcfg, workers: int,
 
 
 def make_lm_model_fn(*, n_params: int, seq: int, workers: int,
-                     overlap: str, staleness: int = 1):
-    """The roofline ``model_fn`` for the training CLI: local-step work is
-    the LM rule fwd+bwd ~ 6*N flops per token; the consensus payload is
-    the flat engine's worker-row all-gather (R x n fp32) plus the (R, R)
-    partial-Gram psum — the same accounting as
-    ``microbench.bench_overlap_round``."""
+                     overlap: str, device_kind: str, staleness: int = 1):
+    """The roofline ``model_fn`` for the training CLI on ``device_kind``
+    (``launch.roofline.PEAKS``): local-step work is the LM rule fwd+bwd
+    ~ 6*N flops per token; the consensus payload is the flat engine's
+    worker-row all-gather (R x n fp32) plus the (R, R) partial-Gram psum
+    — the same accounting as ``microbench.bench_overlap_round``."""
     gather_bytes = workers * n_params * 4 + workers * workers * 4
+    peak_flops = rf.peaks(device_kind)["flops"]
 
     def model_us(cand: Candidate) -> float:
-        work_s = 6.0 * n_params * cand.batch * seq / rf.PEAK_FLOPS
+        work_s = 6.0 * n_params * cand.batch * seq / peak_flops
         return rf.probe_round_model(
             work_s_per_step=work_s, tau=cand.tau,
-            gather_bytes=gather_bytes, R=workers, mode=overlap,
-            staleness=staleness) * 1e6
+            gather_bytes=gather_bytes, device_kind=device_kind, R=workers,
+            mode=overlap, staleness=staleness) * 1e6
     return model_us

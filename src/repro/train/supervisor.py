@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import time
 
 import jax
 import numpy as np
@@ -282,6 +283,9 @@ class Supervisor:
         self.batch_size = int(batch_size)
         self.logger = logger
         self.on_round = on_round
+        # wall seconds of each completed round: batch, step and the wait
+        # for its results (a replayed round counts once more)
+        self.round_wall_s = []
         self.place_fn = place_fn
         self.sleep_fn = sleep_fn
         self.seed = seed
@@ -453,11 +457,15 @@ class Supervisor:
                 else:
                     self._degrade_streak = 0
                 state = set_participation(state, mask, sync=sync)
+            t_round = time.perf_counter()
             try:
                 if self.chaos is not None:
                     self.chaos.before_step(spec.index, self.batch_size)
                 batch = batch_fn(spec, self.batch_size)
                 state, metrics = step_fn(state, batch)
+                # the round's outputs come from one executable: once its
+                # metrics are ready the whole round has run
+                jax.block_until_ready(metrics)
             except Exception as e:   # noqa: BLE001 — policy: retry w/ budget
                 consec_fail += 1
                 oom = is_oom(e)
@@ -483,6 +491,7 @@ class Supervisor:
                 i = restored
                 continue
             consec_fail = 0
+            self.round_wall_s.append(time.perf_counter() - t_round)
             if self.on_round is not None:
                 self.on_round(spec, metrics)
             if self.logger is not None:

@@ -371,9 +371,10 @@ def make_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
                 engine, dcfg, lam_t, losses=snap["losses"],
                 grad_norms=snap["gns"], pull_scale=ps)
             T1 = stages[0][1]
-            n_eff = max(1, min(dcfg.overlap_chunks, engine.layout.n))
+            width = snap["x"].shape[-1]
+            n_eff = max(1, min(dcfg.overlap_chunks, width))
             gram = None
-            for a, b in _chunk_bounds(engine.layout.n, n_eff):
+            for a, b in _chunk_bounds(width, n_eff):
                 part = engine.stage_comm(snap["x"][:, a:b], T1)
                 gram = part if gram is None else gram + part
             new_snap = {"x": params, "losses": losses[-1], "gns": gns[-1]}
@@ -434,9 +435,10 @@ def make_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
                 engine, dcfg, lam_t, losses=sl, grad_norms=sg,
                 mask=act_old, pull_scale=ps)
             T1 = stages[0][1]
-            n_eff = max(1, min(dcfg.overlap_chunks, engine.layout.n))
+            width = s_old.shape[-1]
+            n_eff = max(1, min(dcfg.overlap_chunks, width))
             gram = None
-            for a, b in _chunk_bounds(engine.layout.n, n_eff):
+            for a, b in _chunk_bounds(width, n_eff):
                 part = engine.stage_comm(s_old[:, a:b], T1)
                 gram = part if gram is None else gram + part
             q = params
@@ -587,7 +589,6 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
     ``dcfg.elastic`` threads the per-row participation mask through the
     same carry on flat Wx1 and hierarchical WxFxM meshes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import ring_gather
@@ -613,7 +614,8 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
             raise ValueError("make_sharded_round_step requires the flat "
                              "engine (DPPFConfig.engine='flat')")
         L = engine.layout
-        M, n, aux = L.M, L.n, L.aux
+        # n: the view's columns (the parameters plus any kernel padding)
+        M, n, aux = L.M, state.params.shape[-1], L.aux
         if row_size > 1 and M % row_size:
             raise ValueError(
                 f"workers ({M}) not divisible over worker axes "
@@ -1010,9 +1012,9 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
             in_specs.append(P(None, col_e))
             out_specs.append(P(None, col_e))
 
-        res = list(shard_map(
+        res = list(jax.shard_map(
             mapped, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=tuple(out_specs), check_rep=False)(*args))
+            out_specs=tuple(out_specs), check_vma=False)(*args))
         new_w, opt_st, t, rnd, metrics = res[:5]
         rest = res[5:]
         cstate = {"g_ema": rest.pop()} if lpf else state.cstate

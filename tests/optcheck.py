@@ -189,7 +189,8 @@ def main():
     from repro.launch.roofline import serving_model
     expect_raises(ValueError,
                   lambda: serving_model(ARCHS["gemma2-2b"], max_slots=0,
-                                        chunk=1, state_bytes_per_slot=1),
+                                        chunk=1, state_bytes_per_slot=1,
+                                        device_kind="TPU v5 lite"),
                   "serving_model zero slots")
 
     # autotune surface (--autotune CI leg runs under -O): the search
@@ -212,7 +213,8 @@ def main():
     from repro.launch.roofline import probe_round_model
     expect_raises(ValueError,
                   lambda: probe_round_model(work_s_per_step=1e-6, tau=4,
-                                            gather_bytes=1e6, mode="bogus"),
+                                            gather_bytes=1e6, mode="bogus",
+                                            device_kind="TPU v5 lite"),
                   "probe_round_model unknown overlap mode")
 
     import tempfile, os
@@ -263,6 +265,7 @@ def main():
                   lambda: supervisor_model(rounds=2, tau=2,
                                            work_s_per_step=1e-3,
                                            gather_bytes=1e6,
+                                           device_kind="TPU v5 lite",
                                            degraded_rounds=3),
                   "supervisor_model degraded_rounds > rounds")
 

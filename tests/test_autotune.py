@@ -502,7 +502,8 @@ def test_reconcile_scale_never_changes_argmin():
 
 
 def test_probe_round_model_mode_ordering():
-    kw = dict(work_s_per_step=1e-4, tau=4, gather_bytes=5e7, R=8)
+    kw = dict(work_s_per_step=1e-4, tau=4, gather_bytes=5e7, R=8,
+              device_kind="TPU v5 lite")
     exact = rf.probe_round_model(mode="none", **kw)
     s1 = rf.probe_round_model(mode="staleness1", **kw)
     db = rf.probe_round_model(mode="doublebuf", **kw)
@@ -516,14 +517,15 @@ def test_probe_round_model_mode_ordering():
 def test_probe_round_model_validation():
     with pytest.raises(ValueError, match="overlap mode"):
         rf.probe_round_model(work_s_per_step=1e-4, tau=4,
-                             gather_bytes=1e6, mode="bogus")
+                             gather_bytes=1e6, mode="bogus",
+                             device_kind="TPU v5 lite")
     with pytest.raises(ValueError, match="tau"):
         rf.probe_round_model(work_s_per_step=1e-4, tau=0,
-                             gather_bytes=1e6)
+                             gather_bytes=1e6, device_kind="TPU v5 lite")
     with pytest.raises(ValueError, match="staleness"):
         rf.probe_round_model(work_s_per_step=1e-4, tau=2,
                              gather_bytes=1e6, mode="staleness_k",
-                             staleness=0)
+                             staleness=0, device_kind="TPU v5 lite")
 
 
 def test_lm_model_fn_per_sample_monotone():
@@ -532,7 +534,7 @@ def test_lm_model_fn_per_sample_monotone():
     the max-feasible-batch / best-(tau, chunks) point wins under the
     model for ANY calibration scale."""
     mf = make_lm_model_fn(n_params=10 ** 6, seq=64, workers=8,
-                          overlap="doublebuf")
+                          overlap="doublebuf", device_kind="TPU v5 lite")
     for tau in (2, 4, 8):
         scores = [per_sample_us(mf(Candidate(b, tau, 1)),
                                 Candidate(b, tau, 1))
@@ -546,9 +548,11 @@ def test_lm_model_fn_per_sample_monotone():
 
 def test_lm_model_fn_staleness_depth():
     k1 = make_lm_model_fn(n_params=10 ** 6, seq=64, workers=8,
-                          overlap="staleness_k", staleness=1)
+                          overlap="staleness_k", staleness=1,
+                          device_kind="TPU v5 lite")
     k4 = make_lm_model_fn(n_params=10 ** 6, seq=64, workers=8,
-                          overlap="staleness_k", staleness=4)
+                          overlap="staleness_k", staleness=4,
+                          device_kind="TPU v5 lite")
     c = Candidate(2, 2, 1)
     assert k4(c) <= k1(c)
 
@@ -584,7 +588,7 @@ def test_real_probe_runner_with_injected_oom():
         make_round_probe_runner(p0, mlp_loss, opt, dcfg, M, batch_fn,
                                 reps=1), 3)
     mf = make_lm_model_fn(n_params=dim * 8 + 8 * ncls, seq=1, workers=M,
-                          overlap="doublebuf")
+                          overlap="doublebuf", device_kind="TPU v5 lite")
     plan = run(runner, mf, TuneSpace(min_batch=1, max_batch=8,
                                      taus=(2,), chunks=(1,),
                                      probe_budget=8))

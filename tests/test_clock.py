@@ -401,7 +401,7 @@ def test_launcher_resume_revalidates_clock_position(tmp_path):
     main(args + ["--steps", "8"])                 # writes resume at t=8
     shutil.copy(str(tmp_path / "ck.state.npz"),
                 str(tmp_path / "t8.state.npz"))
-    loss = main(args + ["--steps", "16"])         # t=8 is round 2 of 4
+    loss = main(args + ["--steps", "16"]).eval_loss   # t=8: round 2 of 4
     assert np.isfinite(loss)
     shutil.copy(str(tmp_path / "t8.state.npz"),
                 str(tmp_path / "ck.state.npz"))   # back to the t=8 point
@@ -416,7 +416,7 @@ def test_launcher_cli_qsr_smoke():
     loss = main(["--arch", "yi-6b", "--smoke", "--workers", "2",
                  "--tau", "4", "--steps", "10", "--seq", "16", "--batch",
                  "2", "--lr", "0.3", "--tau-schedule", "qsr", "--qsr-beta",
-                 "0.35"])
+                 "0.35"]).eval_loss
     assert np.isfinite(loss)
 
 
@@ -455,7 +455,7 @@ def test_launcher_log_every_round_jsonl(tmp_path):
     loss = main(["--arch", "yi-6b", "--smoke", "--workers", "2",
                  "--tau", "4", "--steps", "10", "--seq", "16", "--batch",
                  "2", "--lr", "0.3", "--overlap", "doublebuf",
-                 "--overlap-chunks", "2", "--log-every-round", path])
+                 "--overlap-chunks", "2", "--log-every-round", path]).eval_loss
     assert np.isfinite(loss)
     rows = [json.loads(l) for l in open(path)]
     clock = RoundClock(total_steps=10, tau=4, base_lr=0.3,
@@ -474,7 +474,7 @@ def test_launcher_log_every_round_jsonl(tmp_path):
     ddp_path = str(tmp_path / "ddp.jsonl")
     loss = main(["--arch", "yi-6b", "--smoke", "--workers", "2",
                  "--consensus", "ddp", "--steps", "3", "--seq", "16",
-                 "--batch", "2", "--log-every-round", ddp_path])
+                 "--batch", "2", "--log-every-round", ddp_path]).eval_loss
     assert np.isfinite(loss)
     rows = [json.loads(l) for l in open(ddp_path)]
     assert len(rows) == 3 and all(r["tau"] == 1 for r in rows)

@@ -346,11 +346,13 @@ def test_serving_roofline_validation_and_bounds():
     from repro.launch.roofline import serving_model
     cfg = ARCHS["gemma2-2b"]
     with pytest.raises(ValueError):
-        serving_model(cfg, max_slots=0, chunk=1, state_bytes_per_slot=1)
+        serving_model(cfg, max_slots=0, chunk=1, state_bytes_per_slot=1,
+                      device_kind="TPU v5 lite")
     with pytest.raises(ValueError):
-        serving_model(cfg, max_slots=1, chunk=0, state_bytes_per_slot=1)
+        serving_model(cfg, max_slots=1, chunk=0, state_bytes_per_slot=1,
+                      device_kind="TPU v5 lite")
     r = serving_model(cfg, max_slots=64, chunk=256,
-                      state_bytes_per_slot=10 ** 9)
+                      state_bytes_per_slot=10 ** 9, device_kind="TPU v5 lite")
     assert r["decode_bound"] in ("memory", "compute")
     assert r["prefill_tok_s"] > r["decode_tok_s"]
     assert r["prefill_tokens_per_decode_step"] > 0

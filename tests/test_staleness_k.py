@@ -371,7 +371,6 @@ def test_ring_gather_matches_all_gather_8dev():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_flat_engine_mesh, ring_gather
 
@@ -382,8 +381,8 @@ for m_loc in (1, 3):
         r = ring_gather(v, ("data",), world=8, axis=0)
         g = jax.lax.all_gather(v, ("data",), axis=0, tiled=True)
         return r, g
-    r, g = shard_map(both, mesh=mesh, in_specs=P("data", None),
-                     out_specs=P(None, None), check_rep=False)(x)
+    r, g = jax.shard_map(both, mesh=mesh, in_specs=P("data", None),
+                     out_specs=P(None, None), check_vma=False)(x)
     assert np.array_equal(np.asarray(r), np.asarray(g)), m_loc
     assert np.array_equal(np.asarray(r), np.asarray(x)), m_loc
 print("ALL OK")
