@@ -265,9 +265,10 @@ class ConsensusEngine:
         """Complete a column-dimension contraction. Single-shard: identity.
         Sharded: psum of the per-shard partial over the column axes — the
         (R, R)-sized reduction is the only collective the engine itself
-        ever issues."""
+        ever issues. The device trace names it ``dppf.exchange``."""
         if self.shard is not None and self.shard.col_axes:
-            return jax.lax.psum(partial, self.shard.col_axes)
+            with jax.named_scope("dppf.exchange"):
+                return jax.lax.psum(partial, self.shard.col_axes)
         return partial
 
     def gram(self, flat):

@@ -25,6 +25,11 @@ view once (``core.engine.FlatLayout.width``), so the round never copies.
 
 ``interpret=None`` resolves to ``jax.default_backend() != "tpu"``: the
 kernels compile on a TPU and are interpreted everywhere else.
+
+The engine kernels pass their own function's name as the ``pallas_call``
+``name``: the custom call, and so the device trace's instruction, is
+named after it (``%fused_round.<n>``, ``%partial_gram.<n>``,
+``%mix_shard.<n>``), whatever function encloses the call.
 """
 from __future__ import annotations
 
@@ -289,6 +294,7 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
         ],
         input_output_aliases={0: 0},
         interpret=_interpret(interpret),
+        name="fused_round",
     )(_pad_cols(flat, width), T.astype(jnp.float32), _row_vec(c0, R),
       _row_vec(c1, R))
     return (out if width == n else out[:, :n]), r[:, 0], G
@@ -343,6 +349,7 @@ def partial_gram(flat, *, block_cols=2048, interpret=None):
         out_specs=pl.BlockSpec((R, R), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, R), jnp.float32),
         interpret=_interpret(interpret),
+        name="partial_gram",
     )(_pad_cols(flat, width))
 
 
@@ -367,6 +374,7 @@ def mix_shard(flat, T, coef, *, block_cols=2048, interpret=None):
         out_shape=jax.ShapeDtypeStruct((R, width), jnp.float32),
         input_output_aliases={1: 0},
         interpret=_interpret(interpret),
+        name="mix_shard",
     )(_row_vec(coef, R), _pad_cols(flat, width), T.astype(jnp.float32))
     return out if width == n else out[:, :n]
 

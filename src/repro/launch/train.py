@@ -193,10 +193,6 @@ def main(argv=None) -> TrainRun:
                          "staleness, plus the clock position) per round to "
                          "PATH (train.clock.RoundMetricsLogger; the ddp "
                          "branch logs per step on its tau=1 clock)")
-    ap.add_argument("--legacy-metrics", action="store_true",
-                    help="re-emit the deprecated boolean 'stale' field "
-                         "next to the integer 'staleness' in "
-                         "--log-every-round records")
     ap.add_argument("--autotune", action="store_true",
                     help="probe-search the operating point before training "
                          "(train.autotune, DESIGN.md §Autotune): power-of-"
@@ -407,8 +403,7 @@ def main(argv=None) -> TrainRun:
         clock = RoundClock.from_config(dcfg, base_lr=args.lr,
                                        total_steps=args.steps,
                                        warmup=args.warmup)
-    logger = RoundMetricsLogger(args.log_every_round,
-                                legacy=args.legacy_metrics) \
+    logger = RoundMetricsLogger(args.log_every_round) \
         if args.log_every_round else None
 
     t0 = time.time()
@@ -530,12 +525,16 @@ def main(argv=None) -> TrainRun:
             lambda spec, bs: make_round_batch(task, args.seed, args.workers,
                                               spec.tau, spec.start, bs, cfg),
             start_round=int(state.round))
-        if sup.events:
-            s = sup.summary()
+        s = sup.summary()
+        if s["event_seq"]:
             print("supervisor events: " + " ".join(s["event_seq"]))
             print("supervisor counters: " + " ".join(
                 f"{k}={v}" for k, v in s["counters"].items())
                   + f" final_batch={s['final_batch']}")
+        if s["compiles"]:
+            # recompile events (round, seconds) are in --log-every-round
+            print("supervisor compiles: " + " ".join(
+                f"{k}={v}" for k, v in s["compiles"].items()))
         print(f"comm rounds {clock.total_rounds} "
               f"(fixed tau={args.tau} would take {clock.fixed_rounds}; "
               f"all-reduces saved {clock.fixed_rounds - clock.total_rounds})")

@@ -465,23 +465,17 @@ class RoundMetricsLogger:
     the consensus fields are zeros and the clock is the tau=1 per-step
     clock; pass a plain step index instead of a spec there). ``staleness``
     is the integer depth of the consensus the round applied (0 = exact,
-    k = the round-(r-k) snapshot); a legacy boolean ``stale`` key (the
-    pre-staleness_k schema, where 0/1 IS the depth) is normalized to
-    ``staleness`` so old emitters and old JSONL stay readable. Each line
-    carries the clock position (round, global start step, tau) plus the
-    metrics, so a QSR-adaptive run's log is self-describing. Values are
-    converted via ``float`` — call it OUTSIDE jit (on the returned
-    metrics), never inside a traced function.
-    ``launch/train.py --log-every-round PATH`` wires it
-    (``--legacy-metrics`` for the PR 7 compat ``stale`` boolean).
+    k = the round-(r-k) snapshot). Each line carries the clock position
+    (round, global start step, tau) plus the metrics, so a QSR-adaptive
+    run's log is self-describing. Values are converted via ``float`` —
+    call it OUTSIDE jit (on the returned metrics), never inside a traced
+    function. ``launch/train.py --log-every-round PATH`` wires it; the
+    supervisor's events (``recompile`` among them) arrive as rows with
+    an ``event`` key.
     """
 
-    def __init__(self, path: str, *, legacy: bool = False):
+    def __init__(self, path: str):
         self.path = path
-        # legacy=True re-emits the pre-staleness_k boolean ``stale`` key
-        # NEXT TO the integer ``staleness`` (old downstream parsers); the
-        # default emits only ``staleness`` — no double key
-        self.legacy = legacy
         d = os.path.dirname(os.path.abspath(path))
         os.makedirs(d, exist_ok=True)
         self._fh = open(path, "w")
@@ -492,19 +486,10 @@ class RoundMetricsLogger:
         else:   # ddp / per-step drivers: a bare global step index
             row = {"round": int(spec), "start": int(spec), "tau": 1}
         for k, v in metrics.items():
-            if k == "stale":
-                if "staleness" in metrics:
-                    # modern emitters carry the integer depth; drop the
-                    # duplicate boolean instead of double-emitting it
-                    continue
-                # legacy emitters: the boolean flag's 0/1 IS the depth
-                k = "staleness"
             try:
                 row[k] = float(v)
             except (TypeError, ValueError):
                 row[k] = str(v)
-        if self.legacy and "staleness" in row:
-            row["stale"] = bool(row["staleness"] > 0)
         self._fh.write(json.dumps(row) + "\n")
         self._fh.flush()
         return row

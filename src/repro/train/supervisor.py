@@ -34,6 +34,18 @@ replayable artifact. Determinism contract: no wall clocks and no global
 RNG — backoff jitter is a sha256 of ``(seed, round, attempt)``, recorded
 in the event and only actually slept when a ``sleep_fn`` is provided
 (CI runs on virtual time).
+
+The loop marks what it is doing on the profiler's trace (the device
+trace's clock): each attempt of a round is a ``dppf.round`` step
+annotation, and inside it ``dppf.membership``, ``dppf.batch``,
+``dppf.dispatch``, ``dppf.wait``, ``dppf.report``, ``dppf.checkpoint``
+and ``dppf.recover`` spans name the host's work, so a device idle gap
+reads as the span it falls in. Without a profiler they cost about a
+microsecond each. Compiles are counted too (``counters["compile"]``); a
+compile in any attempt but the run's first also emits a ``recompile``
+event with its seconds. Both depend on the process's compile caches, so
+they stay out of the replay-pinned ``event_seq`` and ``summary``
+counters (``summary()["compiles"]`` holds them).
 """
 from __future__ import annotations
 
@@ -43,10 +55,15 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import load_train_state, save_train_state
 from repro.train.autotune import is_oom
 from repro.train.trainer import set_participation
+
+# the jax.monitoring duration event of one backend compile (a read from
+# the persistent compile cache included)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 ACTIVE = "active"
 SUSPECT = "suspect"
@@ -299,7 +316,7 @@ class Supervisor:
     # -- events --------------------------------------------------------------
 
     def _emit(self, round_idx, event, *, worker=None, detail="",
-              backoff_s=None, attempt=None):
+              backoff_s=None, attempt=None, seconds=None):
         ev = {"round": int(round_idx), "event": str(event)}
         if worker is not None:
             ev["worker"] = int(worker)
@@ -309,6 +326,8 @@ class Supervisor:
             ev["backoff_s"] = round(float(backoff_s), 3)
         if attempt is not None:
             ev["attempt"] = int(attempt)
+        if seconds is not None:
+            ev["seconds"] = round(float(seconds), 3)
         self.events.append(ev)
         self.counters[ev["event"]] = self.counters.get(ev["event"], 0) + 1
         if self.logger is not None:
@@ -317,13 +336,17 @@ class Supervisor:
 
     def event_seq(self):
         """The compact replay-pinned form: ``r<round>:<event>[:w<worker>]``
-        strings in emission order."""
+        strings in emission order (compile events left out)."""
         return [f"r{e['round']}:{e['event']}"
                 + (f":w{e['worker']}" if "worker" in e else "")
-                for e in self.events]
+                for e in self.events if e["event"] != "recompile"]
 
     def summary(self):
-        return {"counters": dict(sorted(self.counters.items())),
+        compiles = ("compile", "recompile")
+        return {"counters": {k: v for k, v in sorted(self.counters.items())
+                             if k not in compiles},
+                "compiles": {k: self.counters[k] for k in compiles
+                             if k in self.counters},
                 "event_seq": self.event_seq(),
                 "final_batch": self.batch_size}
 
@@ -421,22 +444,60 @@ class Supervisor:
         original exception propagates. NOTE on donation: a failed donated
         step may have invalidated the input buffers, which is exactly why
         recovery always goes through the checkpoint restore, never by
-        re-using the pre-step state object."""
-        rounds = self.clock.rounds
-        like = None
-        if self.ckpt_dir:
-            os.makedirs(self.ckpt_dir, exist_ok=True)
-            # host-side template for restores, captured BEFORE the first
-            # donated call while the buffers are valid
-            like = jax.tree.map(
-                lambda a: np.asarray(jax.device_get(a)), state)
-            self._save(state, start_round - 1)
-        i = start_round
-        consec_fail = 0
-        while i < len(rounds):
-            spec = rounds[i]
-            sync = 1.0
-            if self.membership is not None:
+        re-using the pre-step state object. A compile listener is
+        registered for the call alone (module docstring)."""
+        compiles = []
+
+        def on_compile(event, duration, **_):
+            if event == COMPILE_EVENT:
+                compiles.append(duration)
+
+        jax.monitoring.register_event_duration_secs_listener(on_compile)
+        try:
+            rounds = self.clock.rounds
+            like = None
+            if self.ckpt_dir:
+                os.makedirs(self.ckpt_dir, exist_ok=True)
+                # host-side template for restores, captured BEFORE the
+                # first donated call while the buffers are valid
+                like = jax.tree.map(
+                    lambda a: np.asarray(jax.device_get(a)), state)
+                with TraceAnnotation("dppf.checkpoint"):
+                    self._save(state, start_round - 1)
+            i, consec_fail, first = start_round, 0, True
+            while i < len(rounds):
+                spec = rounds[i]
+                seen = len(compiles)
+                try:
+                    with StepTraceAnnotation("dppf.round",
+                                             step_num=spec.index):
+                        state, i, consec_fail = self._attempt(
+                            state, step_fn, batch_fn, i, consec_fail, like)
+                finally:
+                    self._count_compiles(spec.index, compiles[seen:], first)
+                first = False
+            return state
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_compile)
+
+    def _count_compiles(self, round_idx, seconds, first):
+        if not seconds:
+            return
+        self.counters["compile"] = self.counters.get("compile", 0) \
+            + len(seconds)
+        if not first:
+            self._emit(round_idx, "recompile",
+                       detail=f"{len(seconds)} compile(s)",
+                       seconds=sum(seconds))
+
+    def _attempt(self, state, step_fn, batch_fn, i, consec_fail, like):
+        """One attempt of round ``clock.rounds[i]``. Returns ``(state,
+        the next round's index, consecutive failures)``: ``i + 1`` after
+        a success, the restored round after a recovered failure."""
+        spec = self.clock.rounds[i]
+        sync = 1.0
+        if self.membership is not None:
+            with TraceAnnotation("dppf.membership"):
                 mask = self._mask(spec.index)
                 n_active = int(mask.sum())
                 if self.quorum and n_active < self.quorum:
@@ -457,16 +518,20 @@ class Supervisor:
                 else:
                     self._degrade_streak = 0
                 state = set_participation(state, mask, sync=sync)
-            t_round = time.perf_counter()
-            try:
-                if self.chaos is not None:
-                    self.chaos.before_step(spec.index, self.batch_size)
+        t_round = time.perf_counter()
+        try:
+            if self.chaos is not None:
+                self.chaos.before_step(spec.index, self.batch_size)
+            with TraceAnnotation("dppf.batch"):
                 batch = batch_fn(spec, self.batch_size)
+            with TraceAnnotation("dppf.dispatch"):
                 state, metrics = step_fn(state, batch)
-                # the round's outputs come from one executable: once its
-                # metrics are ready the whole round has run
+            # the round's outputs come from one executable: once its
+            # metrics are ready the whole round has run
+            with TraceAnnotation("dppf.wait"):
                 jax.block_until_ready(metrics)
-            except Exception as e:   # noqa: BLE001 — policy: retry w/ budget
+        except Exception as e:   # noqa: BLE001 — policy: retry w/ budget
+            with TraceAnnotation("dppf.recover"):
                 consec_fail += 1
                 oom = is_oom(e)
                 if oom:
@@ -488,15 +553,14 @@ class Supervisor:
                            backoff_s=b, attempt=consec_fail)
                 if self.sleep_fn is not None:
                     self.sleep_fn(b)
-                i = restored
-                continue
-            consec_fail = 0
-            self.round_wall_s.append(time.perf_counter() - t_round)
+                return state, restored, consec_fail
+        self.round_wall_s.append(time.perf_counter() - t_round)
+        with TraceAnnotation("dppf.report"):
             if self.on_round is not None:
                 self.on_round(spec, metrics)
             if self.logger is not None:
                 self.logger(spec, metrics)
-            if self.ckpt_dir and (spec.index + 1) % self.ckpt_every == 0:
+        if self.ckpt_dir and (spec.index + 1) % self.ckpt_every == 0:
+            with TraceAnnotation("dppf.checkpoint"):
                 self._save(state, spec.index)
-            i += 1
-        return state
+        return state, i + 1, 0

@@ -135,20 +135,39 @@ def _grad_norm(grads):
                         for g in jax.tree.leaves(grads)))
 
 
-def _scan_local_steps(loss, opt: Optimizer, p0, opt_st, t0, batch, *,
-                      clock: RoundClock, sam_rho):
+def _scan_local_steps(loss_fn, opt: Optimizer, p0, opt_st, t0, batch, *,
+                      clock: RoundClock, sam_rho, view=None):
     """The tau purely-local steps shared by every round builder:
     ``lax.scan`` over the batch's leading (tau) dim, vmap over the worker
-    dim of ``p0``/``opt_st``/``batch[:, m]``. Returns
-    ``(params, opt_st, t, losses, gns)`` with losses/gns shaped (tau, M)."""
+    dim of ``p0``/``opt_st``/``batch[:, m]``. ``view`` maps a worker's
+    parameters as carried (a flat-engine row) to the tree ``loss_fn``
+    takes. Returns ``(params, opt_st, t, losses, gns)`` with losses/gns
+    shaped (tau, M).
+
+    Each layer sits under a named scope that the device trace carries
+    (``tf_op``): ``dppf.view`` (the row's slices and casts; under the
+    gradient its transposes too), ``dppf.model`` (forward as
+    ``jvp(dppf.model)``, backward as ``transpose(jvp(dppf.model))``) and
+    ``dppf.update`` (LR, gradient norm, optimizer step), all inside
+    ``dppf.local``, the scan: ops that the compiler makes in the loop
+    without op metadata (the whole view's cast, the embedding gradient's
+    scatter, layout copies) take the loop's name on the chip's trace."""
+    def loss(p, b):
+        if view is not None:
+            with jax.named_scope("dppf.view"):
+                p = view(p)
+        with jax.named_scope("dppf.model"):
+            return loss_fn(p, b)
+
     def local_step(p, o, b, t):
         if sam_rho > 0:
             (loss_v, _), g = sam_gradient(loss, p, b, sam_rho)
         else:
             (loss_v, _), g = jax.value_and_grad(loss, has_aux=True)(p, b)
-        lr = clock.lr_at(t)
-        gn = _grad_norm(g)
-        p, o = opt.step(p, g, o, lr)
+        with jax.named_scope("dppf.update"):
+            lr = clock.lr_at(t)
+            gn = _grad_norm(g)
+            p, o = opt.step(p, g, o, lr)
         return p, o, loss_v, gn
 
     def micro(carry, mb):
@@ -157,8 +176,9 @@ def _scan_local_steps(loss, opt: Optimizer, p0, opt_st, t0, batch, *,
             local_step, in_axes=(0, 0, 0, None))(params, opt_state, mb, t)
         return (params, opt_state, t + 1), (losses, gns)
 
-    (params, opt_st, t), (losses, gns) = jax.lax.scan(
-        micro, (p0, opt_st, t0), batch)
+    with jax.named_scope("dppf.local"):
+        (params, opt_st, t), (losses, gns) = jax.lax.scan(
+            micro, (p0, opt_st, t0), batch)
     return params, opt_st, t, losses, gns
 
 
@@ -303,213 +323,219 @@ def make_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
             raise ValueError(
                 f"overlap={overlap_mode!r} requires the flat engine")
         if engine is None:
-            loss, p0 = loss_fn, state.params
+            view, p0 = None, state.params
         else:
             # local steps differentiate through the flat rows directly:
             # unflatten_row is slices+reshapes, so grads arrive flat and the
             # optimizer state stays (M, n) — no per-step re-flatten
-            loss = lambda row, b: loss_fn(engine.unflatten_row(row), b)
-            p0 = engine.workers(state.params)
+            view = engine.unflatten_row
+            with jax.named_scope("dppf.view"):
+                p0 = engine.workers(state.params)
 
         params, opt_st, t, losses, gns = _scan_local_steps(
-            loss, opt, p0, state.opt, state.t, batch, clock=clock,
-            sam_rho=sam_rho)
+            loss_fn, opt, p0, state.opt, state.t, batch, clock=clock,
+            sam_rho=sam_rho, view=view)
         if engine is not None:
-            params = engine.with_workers(state.params, params)
+            with jax.named_scope("dppf.view"):
+                params = engine.with_workers(state.params, params)
 
-        # the round ABOUT TO apply its consensus — read the lam schedule at
-        # the clock position, not the post-scan ``t // tau`` (the old
-        # off-by-one that skipped round 0 and shifted the whole trajectory)
-        round_idx = _round_index(state, dcfg)
-        lam_t = clock.lam_at(round_idx)
-        ps = clock.pull_scale_at(round_idx)
-        staleness_depth = jnp.int32(0)
+        with jax.named_scope("dppf.consensus"):
+            # the round ABOUT TO apply its consensus — read the lam schedule
+            # at the clock position, not the post-scan ``t // tau`` (the old
+            # off-by-one that skipped round 0 and shifted the whole
+            # trajectory)
+            round_idx = _round_index(state, dcfg)
+            lam_t = clock.lam_at(round_idx)
+            ps = clock.pull_scale_at(round_idx)
+            staleness_depth = jnp.int32(0)
 
-        def lpf_update(params_now, cst):
-            # EMA-filtered local progress (LPF-SGD): the per-round
-            # parameter delta is the accumulated gradient direction;
-            # filtering it gives the alternative push force. Frozen
-            # elastic rows contribute a zero delta (their scan reverted).
-            if not lpf:
-                return None, cst
-            g = spec.filter_mu * cst["g_ema"] \
-                + (1.0 - spec.filter_mu) * (p0 - engine.workers(params_now))
-            return g, {"g_ema": g}
+            def lpf_update(params_now, cst):
+                # EMA-filtered local progress (LPF-SGD): the per-round
+                # parameter delta is the accumulated gradient direction;
+                # filtering it gives the alternative push force. Frozen
+                # elastic rows contribute a zero delta (their scan reverted).
+                if not lpf:
+                    return None, cst
+                g = spec.filter_mu * cst["g_ema"] + (1.0 - spec.filter_mu) \
+                    * (p0 - engine.workers(params_now))
+                return g, {"g_ema": g}
 
-        if overlap_mode == "staleness1":
-            # staleness-1: consensus of the PREVIOUS round's snapshot; its
-            # collectives have no data dependence on this round's scan, so
-            # the scheduler overlaps them with the tau local steps. The
-            # delta is applied to the fresh post-local-step view; the fresh
-            # view becomes the next round's snapshot.
-            snap = state.snap
-            push_vec, cstate_in = lpf_update(params, state.cstate)
-            c_out, cstate, metrics = consensus.apply_round(
-                snap["x"], dcfg, lam_t, cstate_in,
-                losses=snap["losses"], grad_norms=snap["gns"], engine=engine,
-                push_vec=push_vec, pull_scale=ps)
-            new_snap = {"x": params, "losses": losses[-1], "gns": gns[-1]}
-            # explicit round-0 pipeline bubble: the init snapshot is
-            # (usually) collapsed, and consensus of a collapsed fleet is
-            # noise-floor push (engine docstring) — skip the first delta
-            live = (state.t > 0).astype(jnp.float32)
-            params = params + live * (c_out - snap["x"])
-            staleness_depth = live.astype(jnp.int32)
-        elif overlap_mode == "doublebuf":
-            # double-buffered: the snapshot's stage-1 column contraction is
-            # dispatched in ``overlap_chunks`` pieces with no data
-            # dependence on the scan (under shard_map the matching gather/
-            # psum chunks interleave with the local steps — this builder is
-            # the single-shard reference of the same recursion); the round
-            # boundary runs coefficient math + mixing only. Round 0 is the
-            # pipeline-fill bubble: an EXACT consensus of the fresh q (not
-            # a skipped round — the init snapshot is the collapsed fleet
-            # and carries no information).
-            snap = state.snap
-            push_vec, cstate = lpf_update(params, state.cstate)
-            stages, _ = consensus.lower_stages(
-                engine, dcfg, lam_t, losses=snap["losses"],
-                grad_norms=snap["gns"], pull_scale=ps)
-            T1 = stages[0][1]
-            width = snap["x"].shape[-1]
-            n_eff = max(1, min(dcfg.overlap_chunks, width))
-            gram = None
-            for a, b in _chunk_bounds(width, n_eff):
-                part = engine.stage_comm(snap["x"][:, a:b], T1)
-                gram = part if gram is None else gram + part
-            new_snap = {"x": params, "losses": losses[-1], "gns": gns[-1]}
-            q = params
+            if overlap_mode == "staleness1":
+                # staleness-1: consensus of the PREVIOUS round's snapshot; its
+                # collectives have no data dependence on this round's scan, so
+                # the scheduler overlaps them with the tau local steps. The
+                # delta is applied to the fresh post-local-step view; the fresh
+                # view becomes the next round's snapshot.
+                snap = state.snap
+                push_vec, cstate_in = lpf_update(params, state.cstate)
+                c_out, cstate, metrics = consensus.apply_round(
+                    snap["x"], dcfg, lam_t, cstate_in,
+                    losses=snap["losses"], grad_norms=snap["gns"],
+                    engine=engine, push_vec=push_vec, pull_scale=ps)
+                new_snap = {"x": params, "losses": losses[-1], "gns": gns[-1]}
+                # explicit round-0 pipeline bubble: the init snapshot is
+                # (usually) collapsed, and consensus of a collapsed fleet is
+                # noise-floor push (engine docstring) — skip the first delta
+                live = (state.t > 0).astype(jnp.float32)
+                params = params + live * (c_out - snap["x"])
+                staleness_depth = live.astype(jnp.int32)
+            elif overlap_mode == "doublebuf":
+                # double-buffered: the snapshot's stage-1 column contraction
+                # is dispatched in ``overlap_chunks`` pieces with no data
+                # dependence on the scan (under shard_map the matching
+                # gather/psum chunks interleave with the local steps — this
+                # builder is the single-shard reference of the same
+                # recursion); the round boundary runs coefficient math +
+                # mixing only. Round 0 is the pipeline-fill bubble: an EXACT
+                # consensus of the fresh q (not a skipped round — the init
+                # snapshot is the collapsed fleet and carries no
+                # information).
+                snap = state.snap
+                push_vec, cstate = lpf_update(params, state.cstate)
+                stages, _ = consensus.lower_stages(
+                    engine, dcfg, lam_t, losses=snap["losses"],
+                    grad_norms=snap["gns"], pull_scale=ps)
+                T1 = stages[0][1]
+                width = snap["x"].shape[-1]
+                n_eff = max(1, min(dcfg.overlap_chunks, width))
+                gram = None
+                for a, b in _chunk_bounds(width, n_eff):
+                    part = engine.stage_comm(snap["x"][:, a:b], T1)
+                    gram = part if gram is None else gram + part
+                new_snap = {"x": params, "losses": losses[-1], "gns": gns[-1]}
+                q = params
 
-            def _stale(_):
-                c_out, _, m = consensus.apply_round(
-                    snap["x"], dcfg, lam_t, cstate, losses=snap["losses"],
-                    grad_norms=snap["gns"], engine=engine, first_gram=gram,
+                def _stale(_):
+                    c_out, _, m = consensus.apply_round(
+                        snap["x"], dcfg, lam_t, cstate, losses=snap["losses"],
+                        grad_norms=snap["gns"], engine=engine, first_gram=gram,
+                        push_vec=push_vec, pull_scale=ps)
+                    return q + (c_out - snap["x"]), m
+
+                def _bubble(_):
+                    new, _, m = consensus.apply_round(
+                        q, dcfg, lam_t, cstate, losses=losses[-1],
+                        grad_norms=gns[-1], engine=engine,
+                        push_vec=push_vec, pull_scale=ps)
+                    return new, m
+
+                params, metrics = jax.lax.cond(state.t > 0, _stale, _bubble,
+                                               None)
+                staleness_depth = (state.t > 0).astype(jnp.int32)
+            elif overlap_mode == "staleness_k":
+                # staleness-k pipeline (DESIGN.md §Overlap): the snapshot
+                # carry is a k-deep ring ordered oldest -> newest; slot 0
+                # holds the round-(r-k) snapshot whose consensus applies
+                # after THIS round's scan (doublebuf is the k=1 special case
+                # of the same recursion). Rounds 0..k-1 are pipeline fill:
+                # an EXACT consensus of the fresh post-scan view, gated by a
+                # traced cond on the carried round index (resume-correct).
+                k = dcfg.staleness
+                snap = state.snap
+                s_old = snap["x"][0]
+                sl, sg = snap["losses"][0], snap["gns"][0]
+                elastic = bool(getattr(dcfg, "elastic", False))
+                act_old = eff = None
+                if elastic:
+                    active, missed = snap["active"], snap["missed"]
+                    # bounded staleness: a row that already missed k rounds
+                    # is forced back in this round
+                    eff = jnp.where(missed >= k, jnp.float32(1.0), active)
+                    act_old = snap["act"][0]
+                    # dropped rows freeze: revert this round's local steps
+                    # (params AND optimizer state) bit-exactly
+                    params = engine.with_workers(
+                        params, _row_select(eff, engine.workers(params), p0))
+                    opt_st = jax.tree.map(
+                        lambda nw, ow: _row_select(eff, nw, ow),
+                        opt_st, state.opt)
+                # filtered-grad update AFTER the elastic freeze: frozen rows'
+                # reverted scans contribute a zero delta to the EMA
+                push_vec, cstate = lpf_update(params, state.cstate)
+                # the old slot's stage-1 contraction, chunked like doublebuf
+                # (under shard_map the matching ring-gather + psum chunks
+                # interleave with the scan — this is the single-shard
+                # reference of the same recursion)
+                stages, _ = consensus.lower_stages(
+                    engine, dcfg, lam_t, losses=sl, grad_norms=sg,
+                    mask=act_old, pull_scale=ps)
+                T1 = stages[0][1]
+                width = s_old.shape[-1]
+                n_eff = max(1, min(dcfg.overlap_chunks, width))
+                gram = None
+                for a, b in _chunk_bounds(width, n_eff):
+                    part = engine.stage_comm(s_old[:, a:b], T1)
+                    gram = part if gram is None else gram + part
+                q = params
+
+                def _stale(_):
+                    c_out, _, m = consensus.apply_round(
+                        s_old, dcfg, lam_t, cstate, losses=sl, grad_norms=sg,
+                        engine=engine, first_gram=gram, mask=act_old,
+                        push_vec=push_vec, pull_scale=ps)
+                    return q + (c_out - s_old), m
+
+                def _fill(_):
+                    new, _, m = consensus.apply_round(
+                        q, dcfg, lam_t, cstate, losses=losses[-1],
+                        grad_norms=gns[-1], engine=engine, mask=eff,
+                        push_vec=push_vec, pull_scale=ps)
+                    return new, m
+
+                params, metrics = jax.lax.cond(round_idx >= k, _stale, _fill,
+                                               None)
+                if elastic:
+                    # reception gate: the stale delta was masked by the
+                    # SNAPSHOT-time participation (act_old); a row inactive
+                    # NOW must not receive it either — keep it at its frozen
+                    # q
+                    params = engine.with_workers(
+                        params,
+                        _row_select(eff, engine.workers(params),
+                                    engine.workers(q)))
+                    # EASGD-style catch-up: a row rejoining after >= 1 missed
+                    # rounds pulls toward the active-fleet mean
+                    rejoin = eff * (missed > 0).astype(jnp.float32)
+                    w = engine.workers(params)
+                    mean = jnp.sum(eff[:, None] * w, axis=0) \
+                        / jnp.maximum(jnp.sum(eff), 1.0)
+                    w = w + (dcfg.elastic_catchup * rejoin)[:, None] \
+                        * (mean[None] - w)
+                    params = engine.with_workers(params, w)
+                    if "sync" in snap:
+                        # quorum-degrade gate (train/supervisor.py): sync == 0
+                        # reverts the whole consensus application — stale
+                        # delta, catch-up pull, and the aux-center move —
+                        # leaving every row at its post-freeze local view q
+                        # BIT-exactly (a where select, never arithmetic
+                        # blending); the ring still advances below so the
+                        # pipeline stays resume-correct
+                        params = jnp.where(snap["sync"] > 0, params, q)
+                # advance the ring: drop the consumed slot, append fresh q
+                new_snap = {
+                    "x": jnp.concatenate([snap["x"][1:], q[None]], axis=0),
+                    "losses": jnp.concatenate(
+                        [snap["losses"][1:], losses[-1][None]], axis=0),
+                    "gns": jnp.concatenate(
+                        [snap["gns"][1:], gns[-1][None]], axis=0)}
+                if elastic:
+                    new_snap.update(
+                        act=jnp.concatenate([snap["act"][1:], eff[None]],
+                                            axis=0),
+                        active=active,
+                        missed=jnp.where(eff > 0, 0, missed + 1)
+                        .astype(jnp.int32))
+                    if "sync" in snap:
+                        new_snap["sync"] = snap["sync"]
+                staleness_depth = jnp.where(round_idx >= k, k, 0) \
+                    .astype(jnp.int32)
+            else:
+                push_vec, cstate_in = lpf_update(params, state.cstate)
+                params, cstate, metrics = consensus.apply_round(
+                    params, dcfg, lam_t, cstate_in,
+                    losses=losses[-1], grad_norms=gns[-1], engine=engine,
                     push_vec=push_vec, pull_scale=ps)
-                return q + (c_out - snap["x"]), m
-
-            def _bubble(_):
-                new, _, m = consensus.apply_round(
-                    q, dcfg, lam_t, cstate, losses=losses[-1],
-                    grad_norms=gns[-1], engine=engine,
-                    push_vec=push_vec, pull_scale=ps)
-                return new, m
-
-            params, metrics = jax.lax.cond(state.t > 0, _stale, _bubble,
-                                           None)
-            staleness_depth = (state.t > 0).astype(jnp.int32)
-        elif overlap_mode == "staleness_k":
-            # staleness-k pipeline (DESIGN.md §Overlap): the snapshot
-            # carry is a k-deep ring ordered oldest -> newest; slot 0
-            # holds the round-(r-k) snapshot whose consensus applies
-            # after THIS round's scan (doublebuf is the k=1 special case
-            # of the same recursion). Rounds 0..k-1 are pipeline fill:
-            # an EXACT consensus of the fresh post-scan view, gated by a
-            # traced cond on the carried round index (resume-correct).
-            k = dcfg.staleness
-            snap = state.snap
-            s_old = snap["x"][0]
-            sl, sg = snap["losses"][0], snap["gns"][0]
-            elastic = bool(getattr(dcfg, "elastic", False))
-            act_old = eff = None
-            if elastic:
-                active, missed = snap["active"], snap["missed"]
-                # bounded staleness: a row that already missed k rounds
-                # is forced back in this round
-                eff = jnp.where(missed >= k, jnp.float32(1.0), active)
-                act_old = snap["act"][0]
-                # dropped rows freeze: revert this round's local steps
-                # (params AND optimizer state) bit-exactly
-                params = engine.with_workers(
-                    params, _row_select(eff, engine.workers(params), p0))
-                opt_st = jax.tree.map(
-                    lambda nw, ow: _row_select(eff, nw, ow),
-                    opt_st, state.opt)
-            # filtered-grad update AFTER the elastic freeze: frozen rows'
-            # reverted scans contribute a zero delta to the EMA
-            push_vec, cstate = lpf_update(params, state.cstate)
-            # the old slot's stage-1 contraction, chunked like doublebuf
-            # (under shard_map the matching ring-gather + psum chunks
-            # interleave with the scan — this is the single-shard
-            # reference of the same recursion)
-            stages, _ = consensus.lower_stages(
-                engine, dcfg, lam_t, losses=sl, grad_norms=sg,
-                mask=act_old, pull_scale=ps)
-            T1 = stages[0][1]
-            width = s_old.shape[-1]
-            n_eff = max(1, min(dcfg.overlap_chunks, width))
-            gram = None
-            for a, b in _chunk_bounds(width, n_eff):
-                part = engine.stage_comm(s_old[:, a:b], T1)
-                gram = part if gram is None else gram + part
-            q = params
-
-            def _stale(_):
-                c_out, _, m = consensus.apply_round(
-                    s_old, dcfg, lam_t, cstate, losses=sl, grad_norms=sg,
-                    engine=engine, first_gram=gram, mask=act_old,
-                    push_vec=push_vec, pull_scale=ps)
-                return q + (c_out - s_old), m
-
-            def _fill(_):
-                new, _, m = consensus.apply_round(
-                    q, dcfg, lam_t, cstate, losses=losses[-1],
-                    grad_norms=gns[-1], engine=engine, mask=eff,
-                    push_vec=push_vec, pull_scale=ps)
-                return new, m
-
-            params, metrics = jax.lax.cond(round_idx >= k, _stale, _fill,
-                                           None)
-            if elastic:
-                # reception gate: the stale delta was masked by the
-                # SNAPSHOT-time participation (act_old); a row inactive
-                # NOW must not receive it either — keep it at its frozen q
-                params = engine.with_workers(
-                    params,
-                    _row_select(eff, engine.workers(params),
-                                engine.workers(q)))
-                # EASGD-style catch-up: a row rejoining after >= 1 missed
-                # rounds pulls toward the active-fleet mean
-                rejoin = eff * (missed > 0).astype(jnp.float32)
-                w = engine.workers(params)
-                mean = jnp.sum(eff[:, None] * w, axis=0) \
-                    / jnp.maximum(jnp.sum(eff), 1.0)
-                w = w + (dcfg.elastic_catchup * rejoin)[:, None] \
-                    * (mean[None] - w)
-                params = engine.with_workers(params, w)
-                if "sync" in snap:
-                    # quorum-degrade gate (train/supervisor.py): sync == 0
-                    # reverts the whole consensus application — stale
-                    # delta, catch-up pull, and the aux-center move —
-                    # leaving every row at its post-freeze local view q
-                    # BIT-exactly (a where select, never arithmetic
-                    # blending); the ring still advances below so the
-                    # pipeline stays resume-correct
-                    params = jnp.where(snap["sync"] > 0, params, q)
-            # advance the ring: drop the consumed slot, append fresh q
-            new_snap = {
-                "x": jnp.concatenate([snap["x"][1:], q[None]], axis=0),
-                "losses": jnp.concatenate(
-                    [snap["losses"][1:], losses[-1][None]], axis=0),
-                "gns": jnp.concatenate(
-                    [snap["gns"][1:], gns[-1][None]], axis=0)}
-            if elastic:
-                new_snap.update(
-                    act=jnp.concatenate([snap["act"][1:], eff[None]],
-                                        axis=0),
-                    active=active,
-                    missed=jnp.where(eff > 0, 0, missed + 1)
-                    .astype(jnp.int32))
-                if "sync" in snap:
-                    new_snap["sync"] = snap["sync"]
-            staleness_depth = jnp.where(round_idx >= k, k, 0) \
-                .astype(jnp.int32)
-        else:
-            push_vec, cstate_in = lpf_update(params, state.cstate)
-            params, cstate, metrics = consensus.apply_round(
-                params, dcfg, lam_t, cstate_in,
-                losses=losses[-1], grad_norms=gns[-1], engine=engine,
-                push_vec=push_vec, pull_scale=ps)
-            new_snap = state.snap
+                new_snap = state.snap
         metrics = dict(metrics)
         metrics["train_loss"] = losses.mean()
         metrics["lam_t"] = lam_t
@@ -694,11 +720,14 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
 
             # clock position of the round about to mix (pre-scan index —
             # same off-by-one fix as make_round_step)
-            lam_t = clock.lam_at(rnd0)
-            ps = clock.pull_scale_at(rnd0)
-            loss = lambda row, b: loss_fn(engine.unflatten_row(row), b)
-            w_full = jax.lax.all_gather(w_loc, eff_cols, axis=1, tiled=True) \
-                if eff_cols else w_loc
+            with jax.named_scope("dppf.consensus"):
+                lam_t = clock.lam_at(rnd0)
+                ps = clock.pull_scale_at(rnd0)
+            view = engine.unflatten_row
+            with jax.named_scope("dppf.view"):
+                w_full = jax.lax.all_gather(
+                    w_loc, eff_cols, axis=1, tiled=True) \
+                    if eff_cols else w_loc
 
             if dbuf or sk:
                 # the tau local steps split into n_eff segments; ahead of
@@ -715,9 +744,10 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
                 sl0 = snap_l[0] if sk else snap_l
                 sg0 = snap_g[0] if sk else snap_g
                 act0 = act_ring[0] if elastic else None
-                stages, _ = consensus.lower_stages(
-                    s_engine, dcfg, lam_t, losses=sl0, grad_norms=sg0,
-                    mask=act0, pull_scale=ps)
+                with jax.named_scope("dppf.consensus"):
+                    stages, _ = consensus.lower_stages(
+                        s_engine, dcfg, lam_t, losses=sl0, grad_norms=sg0,
+                        mask=act0, pull_scale=ps)
                 T1 = stages[0][1]
                 n_eff = max(1, min(dcfg.overlap_chunks, tau, n_loc))
                 gram, gath = None, []
@@ -725,192 +755,203 @@ def make_sharded_round_step(loss_fn, opt: Optimizer, dcfg: DPPFConfig, *,
                 l_parts, g_parts = [], []
                 for (ca, cz), (sa, sz) in zip(_chunk_bounds(n_loc, n_eff),
                                               _chunk_bounds(tau, n_eff)):
-                    piece = sx0[:, ca:cz]
-                    if row_size > 1:
-                        piece = ring_gather(
-                            piece, row_axes, world=row_size, axis=0) \
-                            if sk else jax.lax.all_gather(
-                                piece, row_axes, axis=0, tiled=True)
-                    if aux:
-                        piece = jnp.concatenate(
-                            [piece, sa0[:, ca:cz]], axis=0)
-                    gath.append(piece)
-                    part = s_engine.stage_comm(piece, T1)
-                    gram = part if gram is None else gram + part
+                    with jax.named_scope("dppf.consensus"):
+                        piece = sx0[:, ca:cz]
+                        if row_size > 1:
+                            with jax.named_scope("dppf.exchange"):
+                                piece = ring_gather(
+                                    piece, row_axes, world=row_size,
+                                    axis=0) if sk else jax.lax.all_gather(
+                                        piece, row_axes, axis=0, tiled=True)
+                        if aux:
+                            piece = jnp.concatenate(
+                                [piece, sa0[:, ca:cz]], axis=0)
+                        gath.append(piece)
+                        part = s_engine.stage_comm(piece, T1)
+                        gram = part if gram is None else gram + part
                     seg = jax.tree.map(lambda l: l[sa:sz], b_loc)
                     params, opt_st, t, lj, gj = _scan_local_steps(
-                        loss, opt, params, opt_st, t, seg, clock=clock,
-                        sam_rho=sam_rho)
+                        loss_fn, opt, params, opt_st, t, seg, clock=clock,
+                        sam_rho=sam_rho, view=view)
                     l_parts.append(lj)
                     g_parts.append(gj)
                 losses = jnp.concatenate(l_parts, axis=0)
                 gns = jnp.concatenate(g_parts, axis=0)
-                s_full = jnp.concatenate(gath, axis=1)    # (R, n_loc)
+                with jax.named_scope("dppf.consensus"):
+                    s_full = jnp.concatenate(gath, axis=1)    # (R, n_loc)
             else:
                 params, opt_st, t, losses, gns = _scan_local_steps(
-                    loss, opt, w_full, opt_loc, t0, b_loc, clock=clock,
-                    sam_rho=sam_rho)
+                    loss_fn, opt, w_full, opt_loc, t0, b_loc, clock=clock,
+                    sam_rho=sam_rho, view=view)
 
-            eff = eff_loc = None
-            r_off = 0
-            if elastic:
-                # bounded staleness: a row that already missed k rounds is
-                # forced back in; dropped rows freeze bit-exactly (local
-                # steps revert on params AND optimizer state)
-                eff = jnp.where(missed >= k_depth, jnp.float32(1.0), active)
-                if row_size > 1:
-                    r_off = _lin_index(row_axes, sizes) * m_loc
-                    eff_loc = jax.lax.dynamic_slice_in_dim(
-                        eff, r_off, m_loc, 0)
-                else:
-                    eff_loc = eff
-                params = _row_select(eff_loc, params, w_full)
-                opt_st = jax.tree.map(
-                    lambda nw, ow: _row_select(eff_loc, nw, ow),
-                    opt_st, opt_loc)
-
-            # round boundary: back to own columns
-            if eff_cols:
-                c_idx = _lin_index(eff_cols, sizes)
-                q_loc = jax.lax.dynamic_slice_in_dim(
-                    params, c_idx * n_loc, n_loc, 1)
-            else:
-                q_loc = params
-            if row_size > 1:
-                l_last = jax.lax.all_gather(losses[-1], row_axes, tiled=True)
-                g_last = jax.lax.all_gather(gns[-1], row_axes, tiled=True)
-            else:
-                l_last, g_last = losses[-1], gns[-1]
-
-            push_vec = None
-            if lpf:
-                # EMA-filtered local progress (LPF-SGD): the own-row,
-                # own-column delta of this round's scan (zero for frozen
-                # elastic rows — their q reverted to w), row-gathered to
-                # the full (M, n_loc) slab every column shard mixes with
-                delta = w_loc - q_loc
-                if row_size > 1:
-                    delta = jax.lax.all_gather(delta, row_axes, axis=0,
-                                               tiled=True)
-                push_vec = spec.filter_mu * g_ema \
-                    + (1.0 - spec.filter_mu) * delta
-
-            def gather_rows(x_loc, *, ring=False):
-                """Own-column worker rows + aux -> the full (R, n_loc)
-                view (THE consensus all-reduce of the paper). With
-                ``ring=True`` the gather runs over the ppermute ring
-                (bit-identical result, R-1 one-block hops)."""
-                if row_size > 1:
-                    rows = ring_gather(x_loc, row_axes, world=row_size,
-                                       axis=0) if ring \
-                        else jax.lax.all_gather(x_loc, row_axes, axis=0,
-                                                tiled=True)
-                else:
-                    rows = x_loc
-                return jnp.concatenate([rows, aux_loc], axis=0) if aux \
-                    else rows
-
-            def own_rows(full):
-                """Slice this device's worker rows back out."""
-                if row_size > 1:
-                    return jax.lax.dynamic_slice_in_dim(
-                        full[:M], _lin_index(row_axes, sizes) * m_loc,
-                        m_loc, 0)
-                return full[:M]
-
-            if dbuf or sk:
-                # boundary: coefficient math + mix GEMM only. The delta is
-                # applied shard-locally (own worker rows + aux) — no fresh
-                # row gather; the new snapshot is the row-SHARDED q
-                # (staleness_k: appended to the ring, displacing slot 0).
-                def _stale(_):
-                    c_out, _, m = consensus.apply_round(
-                        s_full, dcfg, lam_t, state.cstate, losses=sl0,
-                        grad_norms=sg0, engine=s_engine, first_gram=gram,
-                        mask=act0, push_vec=push_vec, pull_scale=ps)
-                    delta = c_out - s_full
-                    outs = [q_loc + own_rows(delta)]
-                    if aux:
-                        outs.append(aux_loc + delta[M:])
-                    return tuple(outs + [m])
-
-                def _fill(_):
-                    # pipeline fill: EXACT consensus of the fresh q
-                    X = gather_rows(q_loc, ring=sk)
-                    newX, _, m = consensus.apply_round(
-                        X, dcfg, lam_t, state.cstate, losses=l_last,
-                        grad_norms=g_last, engine=s_engine, mask=eff,
-                        push_vec=push_vec, pull_scale=ps)
-                    outs = [own_rows(newX)]
-                    if aux:
-                        outs.append(newX[M:])
-                    return tuple(outs + [m])
-
-                pred = (rnd0 >= k_depth) if sk else (t0 > 0)
-                res = jax.lax.cond(pred, _stale, _fill, None)
-                new_w = res[0]
-                new_aux = res[1] if aux else None
-                metrics = dict(res[-1])
+            with jax.named_scope("dppf.consensus"):
+                eff = eff_loc = None
+                r_off = 0
                 if elastic:
-                    # reception gate: a row inactive NOW keeps its frozen
-                    # q (the stale delta's mask is snapshot-time)
-                    new_w = _row_select(eff_loc, new_w, q_loc)
-                    # EASGD-style catch-up: a row rejoining after >= 1
-                    # missed rounds pulls toward the active-fleet mean
-                    rejoin = eff * (missed > 0).astype(jnp.float32)
-                    partial = jnp.sum(eff_loc[:, None] * new_w, axis=0)
+                    # bounded staleness: a row that already missed k rounds
+                    # is forced back in; dropped rows freeze bit-exactly
+                    # (local steps revert on params AND optimizer state)
+                    eff = jnp.where(missed >= k_depth, jnp.float32(1.0),
+                                    active)
                     if row_size > 1:
-                        partial = jax.lax.psum(partial, row_axes)
-                    mean = partial / jnp.maximum(jnp.sum(eff), 1.0)
-                    cj = dcfg.elastic_catchup * rejoin
-                    cj_loc = jax.lax.dynamic_slice_in_dim(
-                        cj, r_off, m_loc, 0) if row_size > 1 else cj
-                    new_w = new_w + cj_loc[:, None] * (mean[None] - new_w)
-                    if has_sync:
-                        # quorum-degrade gate: sync == 0 reverts the whole
-                        # consensus application — every worker row keeps
-                        # its frozen/post-scan q and the aux center its
-                        # pre-round slab, bit-exactly (where select); the
-                        # ring still advances below
-                        new_w = jnp.where(sync > 0, new_w, q_loc)
-                        if aux:
-                            new_aux = jnp.where(sync > 0, new_aux, aux_loc)
-                if sk:
-                    new_snap_x = jnp.concatenate(
-                        [snap_x[1:], q_loc[None]], axis=0)
-                    new_snap_aux = jnp.concatenate(
-                        [snap_aux[1:], aux_loc[None]], axis=0) if aux \
-                        else None
-                    staleness_depth = jnp.where(
-                        rnd0 >= k_depth, k_depth, 0).astype(jnp.int32)
+                        r_off = _lin_index(row_axes, sizes) * m_loc
+                        eff_loc = jax.lax.dynamic_slice_in_dim(
+                            eff, r_off, m_loc, 0)
+                    else:
+                        eff_loc = eff
+                    params = _row_select(eff_loc, params, w_full)
+                    opt_st = jax.tree.map(
+                        lambda nw, ow: _row_select(eff_loc, nw, ow),
+                        opt_st, opt_loc)
+
+                # round boundary: back to own columns
+                if eff_cols:
+                    c_idx = _lin_index(eff_cols, sizes)
+                    q_loc = jax.lax.dynamic_slice_in_dim(
+                        params, c_idx * n_loc, n_loc, 1)
                 else:
-                    new_snap_x, new_snap_aux = q_loc, aux_loc
-                    staleness_depth = (t0 > 0).astype(jnp.int32)
-            elif stale1:
-                X = gather_rows(q_loc)
-                c_out, cstate, metrics = consensus.apply_round(
-                    snap_x, dcfg, lam_t, state.cstate,
-                    losses=snap_l, grad_norms=snap_g, engine=s_engine,
-                    push_vec=push_vec, pull_scale=ps)
-                new_snap_x, new_snap_aux = X, None
-                # round-0 pipeline bubble, as in make_round_step
-                live = (t0 > 0).astype(jnp.float32)
-                newX = X + live * (c_out - snap_x)
-                new_w = own_rows(newX)
-                new_aux = newX[M:] if aux else None
-                metrics = dict(metrics)
-                staleness_depth = live.astype(jnp.int32)
-            else:
-                X = gather_rows(q_loc)
-                newX, cstate, metrics = consensus.apply_round(
-                    X, dcfg, lam_t, state.cstate,
-                    losses=l_last, grad_norms=g_last, engine=s_engine,
-                    push_vec=push_vec, pull_scale=ps)
-                new_snap_x = new_snap_aux = None
-                new_w = own_rows(newX)
-                new_aux = newX[M:] if aux else None
-                metrics = dict(metrics)
-                staleness_depth = jnp.int32(0)
+                    q_loc = params
+                if row_size > 1:
+                    with jax.named_scope("dppf.exchange"):
+                        l_last = jax.lax.all_gather(losses[-1], row_axes,
+                                                    tiled=True)
+                        g_last = jax.lax.all_gather(gns[-1], row_axes,
+                                                    tiled=True)
+                else:
+                    l_last, g_last = losses[-1], gns[-1]
+
+                push_vec = None
+                if lpf:
+                    # EMA-filtered local progress (LPF-SGD): the own-row,
+                    # own-column delta of this round's scan (zero for frozen
+                    # elastic rows — their q reverted to w), row-gathered to
+                    # the full (M, n_loc) slab every column shard mixes with
+                    delta = w_loc - q_loc
+                    if row_size > 1:
+                        with jax.named_scope("dppf.exchange"):
+                            delta = jax.lax.all_gather(
+                                delta, row_axes, axis=0, tiled=True)
+                    push_vec = spec.filter_mu * g_ema \
+                        + (1.0 - spec.filter_mu) * delta
+
+                def gather_rows(x_loc, *, ring=False):
+                    """Own-column worker rows + aux -> the full (R, n_loc)
+                    view (THE consensus all-reduce of the paper). With
+                    ``ring=True`` the gather runs over the ppermute ring
+                    (bit-identical result, R-1 one-block hops)."""
+                    if row_size > 1:
+                        with jax.named_scope("dppf.exchange"):
+                            rows = ring_gather(
+                                x_loc, row_axes, world=row_size,
+                                axis=0) if ring else jax.lax.all_gather(
+                                    x_loc, row_axes, axis=0, tiled=True)
+                    else:
+                        rows = x_loc
+                    return jnp.concatenate([rows, aux_loc], axis=0) if aux \
+                        else rows
+
+                def own_rows(full):
+                    """Slice this device's worker rows back out."""
+                    if row_size > 1:
+                        return jax.lax.dynamic_slice_in_dim(
+                            full[:M], _lin_index(row_axes, sizes) * m_loc,
+                            m_loc, 0)
+                    return full[:M]
+
+                if dbuf or sk:
+                    # boundary: coefficient math + mix GEMM only. The delta
+                    # is applied shard-locally (own worker rows + aux) — no
+                    # fresh row gather; the new snapshot is the row-SHARDED q
+                    # (staleness_k: appended to the ring, displacing slot 0).
+                    def _stale(_):
+                        c_out, _, m = consensus.apply_round(
+                            s_full, dcfg, lam_t, state.cstate, losses=sl0,
+                            grad_norms=sg0, engine=s_engine, first_gram=gram,
+                            mask=act0, push_vec=push_vec, pull_scale=ps)
+                        delta = c_out - s_full
+                        outs = [q_loc + own_rows(delta)]
+                        if aux:
+                            outs.append(aux_loc + delta[M:])
+                        return tuple(outs + [m])
+
+                    def _fill(_):
+                        # pipeline fill: EXACT consensus of the fresh q
+                        X = gather_rows(q_loc, ring=sk)
+                        newX, _, m = consensus.apply_round(
+                            X, dcfg, lam_t, state.cstate, losses=l_last,
+                            grad_norms=g_last, engine=s_engine, mask=eff,
+                            push_vec=push_vec, pull_scale=ps)
+                        outs = [own_rows(newX)]
+                        if aux:
+                            outs.append(newX[M:])
+                        return tuple(outs + [m])
+
+                    pred = (rnd0 >= k_depth) if sk else (t0 > 0)
+                    res = jax.lax.cond(pred, _stale, _fill, None)
+                    new_w = res[0]
+                    new_aux = res[1] if aux else None
+                    metrics = dict(res[-1])
+                    if elastic:
+                        # reception gate: a row inactive NOW keeps its frozen
+                        # q (the stale delta's mask is snapshot-time)
+                        new_w = _row_select(eff_loc, new_w, q_loc)
+                        # EASGD-style catch-up: a row rejoining after >= 1
+                        # missed rounds pulls toward the active-fleet mean
+                        rejoin = eff * (missed > 0).astype(jnp.float32)
+                        partial = jnp.sum(eff_loc[:, None] * new_w, axis=0)
+                        if row_size > 1:
+                            with jax.named_scope("dppf.exchange"):
+                                partial = jax.lax.psum(partial, row_axes)
+                        mean = partial / jnp.maximum(jnp.sum(eff), 1.0)
+                        cj = dcfg.elastic_catchup * rejoin
+                        cj_loc = jax.lax.dynamic_slice_in_dim(
+                            cj, r_off, m_loc, 0) if row_size > 1 else cj
+                        new_w = new_w + cj_loc[:, None] * (mean[None] - new_w)
+                        if has_sync:
+                            # quorum-degrade gate: sync == 0 reverts the whole
+                            # consensus application — every worker row keeps
+                            # its frozen/post-scan q and the aux center its
+                            # pre-round slab, bit-exactly (where select); the
+                            # ring still advances below
+                            new_w = jnp.where(sync > 0, new_w, q_loc)
+                            if aux:
+                                new_aux = jnp.where(sync > 0, new_aux, aux_loc)
+                    if sk:
+                        new_snap_x = jnp.concatenate(
+                            [snap_x[1:], q_loc[None]], axis=0)
+                        new_snap_aux = jnp.concatenate(
+                            [snap_aux[1:], aux_loc[None]], axis=0) if aux \
+                            else None
+                        staleness_depth = jnp.where(
+                            rnd0 >= k_depth, k_depth, 0).astype(jnp.int32)
+                    else:
+                        new_snap_x, new_snap_aux = q_loc, aux_loc
+                        staleness_depth = (t0 > 0).astype(jnp.int32)
+                elif stale1:
+                    X = gather_rows(q_loc)
+                    c_out, cstate, metrics = consensus.apply_round(
+                        snap_x, dcfg, lam_t, state.cstate,
+                        losses=snap_l, grad_norms=snap_g, engine=s_engine,
+                        push_vec=push_vec, pull_scale=ps)
+                    new_snap_x, new_snap_aux = X, None
+                    # round-0 pipeline bubble, as in make_round_step
+                    live = (t0 > 0).astype(jnp.float32)
+                    newX = X + live * (c_out - snap_x)
+                    new_w = own_rows(newX)
+                    new_aux = newX[M:] if aux else None
+                    metrics = dict(metrics)
+                    staleness_depth = live.astype(jnp.int32)
+                else:
+                    X = gather_rows(q_loc)
+                    newX, cstate, metrics = consensus.apply_round(
+                        X, dcfg, lam_t, state.cstate,
+                        losses=l_last, grad_norms=g_last, engine=s_engine,
+                        push_vec=push_vec, pull_scale=ps)
+                    new_snap_x = new_snap_aux = None
+                    new_w = own_rows(newX)
+                    new_aux = newX[M:] if aux else None
+                    metrics = dict(metrics)
+                    staleness_depth = jnp.int32(0)
 
             train_loss = losses.mean()
             if row_size > 1:
@@ -1118,20 +1159,26 @@ def make_ddp_step(loss_fn, opt: Optimizer, *,
         clock = RoundClock(total_steps=total_steps, tau=1, base_lr=base_lr,
                            warmup=warmup)
 
+    def loss_scoped(p, b):
+        with jax.named_scope("dppf.model"):
+            return loss_fn(p, b)
+
     def step(state: TrainState, batch):
         def per_worker(b):
             if sam_rho > 0:
-                (loss, _), g = sam_gradient(loss_fn, state.params, b, sam_rho)
+                (loss, _), g = sam_gradient(loss_scoped, state.params, b,
+                                            sam_rho)
             else:
-                (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                (loss, _), g = jax.value_and_grad(loss_scoped, has_aux=True)(
                     state.params, b)
             return loss, g
 
         losses, grads = jax.vmap(per_worker)(batch)
-        g = jax.tree.map(lambda a: jnp.mean(a.astype(jnp.float32), axis=0),
-                         grads)
-        lr = clock.lr_at(state.t)
-        params, opt_st = opt.step(state.params, g, state.opt, lr)
+        with jax.named_scope("dppf.update"):
+            g = jax.tree.map(
+                lambda a: jnp.mean(a.astype(jnp.float32), axis=0), grads)
+            lr = clock.lr_at(state.t)
+            params, opt_st = opt.step(state.params, g, state.opt, lr)
         new_state = TrainState(params=params, opt=opt_st, cstate=state.cstate,
                                t=state.t + 1)
         # the unified round-metrics schema (consensus.py::_metrics + the

@@ -432,9 +432,8 @@ def test_round_metrics_logger_jsonl(tmp_path):
     from repro.train import RoundMetricsLogger, RoundSpec
     path = str(tmp_path / "rounds.jsonl")
     with RoundMetricsLogger(path) as log:
-        # a legacy "stale" flag maps onto the unified "staleness" key
         row = log(RoundSpec(index=0, start=0, tau=4),
-                  {"consensus_dist": jnp.float32(1.5), "stale": 0.0,
+                  {"consensus_dist": jnp.float32(1.5), "staleness": 0,
                    "note": "x"})
         assert row == {"round": 0, "start": 0, "tau": 4,
                        "consensus_dist": 1.5, "staleness": 0.0, "note": "x"}
@@ -457,7 +456,11 @@ def test_launcher_log_every_round_jsonl(tmp_path):
                  "2", "--lr", "0.3", "--overlap", "doublebuf",
                  "--overlap-chunks", "2", "--log-every-round", path]).eval_loss
     assert np.isfinite(loss)
-    rows = [json.loads(l) for l in open(path)]
+    lines = [json.loads(l) for l in open(path)]
+    rows = [r for r in lines if "event" not in r]
+    # the tau-2 remainder round compiles its step anew: the supervisor's
+    # recompile event names it
+    assert 2 in [r["round"] for r in lines if r.get("event") == "recompile"]
     clock = RoundClock(total_steps=10, tau=4, base_lr=0.3,
                        overlap="doublebuf")
     assert len(rows) == clock.total_rounds

@@ -320,8 +320,11 @@ def test_supervisor_oom_shrink_restore_replay(tmp_path):
     sup3, _ = _chaos_supervised_run(
         tmp_path, plan, "c",
         logger=lambda spec, m: rows.append((spec, dict(m))))
-    evs = [m["event"] for _, m in rows if "event" in m]
-    assert evs == ["oom", "shrink", "restore", "retry"]
+    evs = [(r, m["event"]) for r, m in rows if "event" in m]
+    assert [e for _, e in evs if e != "recompile"] == [
+        "oom", "shrink", "restore", "retry"]
+    # and the replay at the shrunk batch compiled the step anew
+    assert [r for r, e in evs if e == "recompile"] == [2]
 
 
 def test_supervisor_corrupt_ckpt_ladder(tmp_path):

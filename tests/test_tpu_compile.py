@@ -17,6 +17,7 @@ explicitly.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +88,9 @@ def test_consensus_kernel_compiles_for_v5e(name, one_chip):
     jitted = jax.jit(fn, donate_argnums=() if donate is None else donate)
     compiled = jitted.lower(*[_sds(s, one_chip) for s in shapes]).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the instruction, and so the device trace's op, keeps the kernel's
+    # name, which the benchmark's consensus_kernel_ms matches
+    assert re.search(rf"%{name}\.\d+ = ", compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= R * N * 4 + 64 * 2 ** 20, \
         mem.temp_size_in_bytes
