@@ -93,6 +93,7 @@ def train(argv, log_name, clock):
     c0, h0 = clock.seconds, clock.cache_hits
     run = launcher.main(argv + ["--log-every-round", str(log)])
     rows = [json.loads(line) for line in log.read_text().splitlines()]
+    rows = [row for row in rows if "event" not in row]    # supervisor events
     if len(rows) != len(run.round_s):
         raise RuntimeError(f"{len(rows)} logged rounds, "
                            f"{len(run.round_s)} timed")
@@ -121,14 +122,13 @@ def check_kernel(state, dcfg, lam_t, chunks=16):
     for leaf in jax.tree.leaves(state.opt):     # room for the reference
         leaf.delete()
     (kind, T, c0, c1), = consensus.lower_stages(eng, dcfg, lam_t)[0]
-    out, r_k, _ = pk.fused_round(view, T, c0, c1, eps=eng.eps,
-                                 block_cols=eng.block_cols)
+    out, r_k, _ = pk.fused_round(view, T, c0, c1, eps=eng.eps)
     jax.block_until_ready(out)
     ref = dataclasses.replace(eng, use_kernel=False, precise=True)
     R, width = view.shape
     size = -(-width // chunks)
     bounds = [(a, min(size, width - a)) for a in range(0, width, size)]
-    rho = (width / eng.block_cols + 64) * EPS32
+    rho = (width / pk.VIEW_QUANTUM + 64) * EPS32
 
     def piece(v, a, w):
         return jax.lax.dynamic_slice_in_dim(v, a, w, 1)

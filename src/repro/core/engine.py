@@ -52,9 +52,12 @@ This engine keeps ONE persistent flat view for the whole training run:
   (R, n) buffer per round) for bit-level parity at every scale.
 
   Kernel vs precise: both exact modes differ only in fp32 rounding order.
-  The kernel adds the Gram one column block at a time, so with
-  ``rho = (n / block_cols + 64) * eps32`` a stage's ``r_i`` agree to
-  relative ``rho``, and each output element to
+  The kernel adds the Gram chunk by chunk inside a column block and block
+  by block across the view (``kernels.pullpush.kernel_block``); the
+  precise path contracts the whole width in one fp32 matmul, whose
+  rounding is the larger. With ``rho = (n / VIEW_QUANTUM + 64) * eps32``
+  (``VIEW_QUANTUM`` = 2048 columns) a stage's ``r_i`` agree to relative
+  ``rho``, and each output element to
   ``|c1_i| / r_i * 2 rho * |x - tx| + 4 R eps32 (1 + |1 - coef_i|)
   (|x| + |tx|)`` (the coefficient's error times the gap, plus the
   rounding of the mix; ``tx = T x``). ``chip_smoke.py`` checks the
@@ -63,8 +66,8 @@ This engine keeps ONE persistent flat view for the whole training run:
   view to bf16.
 
   On the kernel path the view's columns are padded once, at ``flatten``,
-  to a whole number of kernel blocks (``FlatLayout.width``); zero columns
-  are inert in every stage and ``unflatten`` drops them.
+  to a multiple of ``pullpush.VIEW_QUANTUM`` (``FlatLayout.width``); zero
+  columns are inert in every stage and ``unflatten`` drops them.
 
 Method semantics (incl. push-from-recomputed-center ordering) mirror
 ``repro.core.consensus.apply_round``'s tree path, which remains the parity
@@ -121,9 +124,9 @@ class FlatLayout:
     offsets: Tuple[int, ...]
     n: int            # parameters per worker
     M: int            # workers
-    # columns of the view: n, padded on the kernel path to a whole number
-    # of kernel column blocks (zero columns are inert in every stage), so
-    # the round never pads or copies the view; unflatten drops them
+    # columns of the view: n, padded on the kernel path to a multiple of
+    # pullpush.VIEW_QUANTUM (zero columns are inert in every stage);
+    # unflatten drops them
     width: int
     aux: int = 0      # extra state rows (easgd center)
 
@@ -166,7 +169,6 @@ class ConsensusEngine:
     interpret: Optional[bool] = None  # Pallas interpret mode; None = off
                                       # on a TPU, on everywhere else
     precise: bool = False         # jnp path: exact gap-space stages
-    block_cols: int = 2048
     eps: float = 1e-12
     # set (dataclasses.replace) inside a shard_map'd round: inputs are then
     # (R, n_local) column shards and column contractions psum over
@@ -197,7 +199,7 @@ class ConsensusEngine:
         width = o
         if kw["use_kernel"]:
             from repro.kernels.pullpush import pullpush as pk
-            width = pk.padded_width(o, kw.get("block_cols", cls.block_cols))
+            width = pk.padded_width(o)
         layout = FlatLayout(treedef=treedef, shapes=shapes, dtypes=dtypes,
                             offsets=tuple(offsets), n=o, M=M, width=width,
                             aux=aux)
@@ -307,7 +309,7 @@ class ConsensusEngine:
         if self.use_kernel:
             from repro.kernels.pullpush import pullpush as pk
             return self._colsum(pk.partial_gram(
-                f, block_cols=self.block_cols, interpret=self.interpret))
+                f, interpret=self.interpret))
         if self.precise:
             g = _mm(T.astype(jnp.float32), f) - f
             return self._colsum(_mm(g, g.T))
@@ -382,16 +384,15 @@ class ConsensusEngine:
                 # one mixing kernel pass (kernels.pullpush.mix_from_gram)
                 new, r, G = pk.mix_from_gram(
                     flat, T, c0, c1, gram, eps=self.eps,
-                    block_cols=self.block_cols, interpret=self.interpret)
+                    interpret=self.interpret)
             elif self.shard is not None and self.shard.col_axes:
                 # column shard: partial-Gram kernel + host-side psum
                 # epilogue + mixing kernel (pullpush.fused_round_sharded)
                 new, r, G = pk.fused_round_sharded(
                     flat, T, c0, c1, axis=self.shard.col_axes, eps=self.eps,
-                    block_cols=self.block_cols, interpret=self.interpret)
+                    interpret=self.interpret)
             else:
                 new, r, G = pk.fused_round(flat, T, c0, c1, eps=self.eps,
-                                           block_cols=self.block_cols,
                                            interpret=self.interpret)
             coef = c0 + c1 / jnp.maximum(r, self.eps)
             W = eye + coef[:, None] * (T - eye)

@@ -16,12 +16,12 @@ kernels live here (DESIGN.md §Consensus-engine):
   one read-modify-write pass. This replaces the per-worker
   ``sq_dist`` + ``apply_update`` pair and their duplicated padding logic.
 
-Block shape (rows, LANE)/(rows, block_cols) keeps the working set in VMEM
-and the lane dimension hardware-aligned. The engine kernels take the worker
-count R itself as the row block (a block equal to the full dimension is
-always legal), so no row is ever padded; columns are padded only when the
-caller's width does not tile into blocks — the engine pads its persistent
-view once (``core.engine.FlatLayout.width``), so the round never copies.
+The reference pair walks (BLOCK_ROWS, LANE) blocks. The engine kernels
+take the worker count R itself as the row block (a block equal to the
+full dimension is always legal), so no row is ever padded, and a column
+block from ``kernel_block``: as wide as fast memory holds at that R, with
+a ragged last block whose columns past the operand's end the Gram masks
+out. No kernel pads or copies its operand, whatever its width.
 
 ``interpret=None`` resolves to ``jax.default_backend() != "tpu"``: the
 kernels compile on a TPU and are interpreted everywhere else.
@@ -160,28 +160,136 @@ def _eye(n, dtype=jnp.float32):
     return (r == c).astype(dtype)
 
 
-def _col_block(n, block_cols):
-    """Column block for an operand of width ``n``: ``n`` itself when it
-    fits one block (a full-dimension block is always legal), else
-    ``block_cols`` rounded to the lane width."""
-    bc = _round_up(block_cols, LANE)
-    return n if n <= bc else bc
+# The engine kernels' column blocks. A grid step costs about a third of a
+# microsecond whatever it moves, so the (R, bc) block is as wide as fast
+# memory allows; the body walks it CHUNK_COLS columns at a time in a loop,
+# so the kernel's code (which Mosaic unrolls over a block's vector
+# registers) stays the size of one chunk's whatever the block. Of 2048,
+# 8192 and 32768 columns, 8192 took the least time over R = 2 and R = 4
+# on a v5e.
+CHUNK_COLS = 8192
+# fast memory for the pipelined blocks: the input and the aliased output,
+# each double-buffered (v5e has 128 MiB a core)
+VMEM_BLOCK_BUDGET = 32 * 2 ** 20
+# the blocks, one chunk's temporaries and Mosaic's own scratch
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=48 * 2 ** 20)
 
 
-def padded_width(n, block_cols=2048):
-    """The width an (R, n) operand tiles into blocks at: the engine pads
-    its persistent view to this once, so no kernel call pads or copies."""
-    return _round_up(n, _col_block(n, block_cols))
+def kernel_block(R, width):
+    """``(bc, n_blocks)``: the column block the engine kernels walk an
+    ``(R, width)`` fp32 operand in, and how many blocks cover it.
+
+    A width of at most ``CHUNK_COLS`` is one full-width block (always a
+    legal block). Wider operands take the widest multiple of
+    ``CHUNK_COLS`` whose four pipelined ``(R, bc)`` buffers, rows counted
+    in whole 8-row sublane tiles, fit ``VMEM_BLOCK_BUDGET``, and no wider
+    than the width; the last block may run past the width (ragged)."""
+    if width <= CHUNK_COLS:
+        return width, 1
+    per_col = 4 * _round_up(R, 8) * 4
+    fit = VMEM_BLOCK_BUDGET // per_col // CHUNK_COLS * CHUNK_COLS
+    bc = min(max(fit, CHUNK_COLS), width // CHUNK_COLS * CHUNK_COLS)
+    return bc, -(-width // bc)
 
 
-def _pad_cols(flat, width):
-    """(R, n) -> fp32 (R, width) with zero columns. A zero column is inert:
-    it adds nothing to any Gram and mixes to zero. Only callers whose
-    width does not tile into blocks pay this copy (column shards and
-    overlap chunks); the engine's persistent view is padded once."""
-    f = flat.astype(jnp.float32)
-    n = f.shape[1]
-    return jnp.pad(f, ((0, 0), (0, width - n))) if width > n else f
+# The engine pads its persistent view's columns to a multiple of this
+# (``core.engine.FlatLayout.width``); zero columns are inert in every stage.
+VIEW_QUANTUM = 2048
+
+
+def padded_width(n):
+    """The width the engine pads its persistent view to: ``n`` rounded up
+    to a multiple of ``VIEW_QUANTUM``, or ``n`` when it is no wider. The
+    kernels take any width; the padding keeps the view's width a whole
+    number of lane tiles."""
+    return n if n <= VIEW_QUANTUM else _round_up(n, VIEW_QUANTUM)
+
+
+def _chunk_gram(x):
+    """Block-centered Gram of one chunk: shifting every column by its
+    row-0 value removes the catastrophic cancellation of an uncentered
+    x @ x.T (entries are O(spread^2), not O(||x||^2)); any zero-sum
+    quadratic form of the sum of such Grams is exact. On the vector unit:
+    column k is the rows' products with row k summed over the lanes, R
+    fp32 vector products a lane tile, where a full-precision MXU
+    contraction pays several passes a tile whatever R."""
+    R = x.shape[0]
+    e = x - x[0:1, :]
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    g = jnp.zeros((R, R), jnp.float32)
+    for k in range(R):
+        s = jnp.sum(e * e[k:k + 1, :], axis=1, keepdims=True)
+        g = g + jnp.where(col == k, s, 0.0)
+    return g
+
+
+def _block_gram(x_ref, valid):
+    """Sum of the chunk Grams over the first ``valid`` (static) columns of
+    the (R, bc) block. A chunk that holds the end of the operand is masked:
+    what a ragged last block holds past the end is undefined, and must add
+    nothing to the Gram."""
+    R, bc = x_ref.shape
+    cb = min(CHUNK_COLS, bc)
+    full, tail = divmod(valid, cb)
+
+    def body(k, g):
+        return g + _chunk_gram(x_ref[:, pl.ds(pl.multiple_of(k * cb, cb),
+                                              cb)])
+
+    if full == 1:      # a block of one chunk, maybe not of whole lane tiles
+        g = _chunk_gram(x_ref[...] if cb == bc else x_ref[:, :cb])
+    else:
+        g = jax.lax.fori_loop(0, full, body, jnp.zeros((R, R), jnp.float32))
+    if tail:
+        x = x_ref[:, pl.ds(full * cb, cb)]
+        col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        g = g + _chunk_gram(jnp.where(col < tail, x, 0.0))
+    return g
+
+
+def _mix(x, t, c):
+    """``tx + (1 - c)(x - tx)``, ``tx = t @ x`` as R broadcast
+    multiply-adds on the vector unit, in fp32."""
+    tx = t[:, 0:1] * x[0:1, :]
+    for k in range(1, x.shape[0]):
+        tx = tx + t[:, k:k + 1] * x[k:k + 1, :]
+    return tx + (1.0 - c) * (x - tx)
+
+
+def _mix_block(x_ref, t, c, o_ref):
+    """``o = tx + (1 - c)(x - tx)``, ``tx = t @ x``, over the (R, bc) block
+    a chunk at a time. Every output column depends on its own input column
+    alone, so a ragged block needs no mask here: what it computes past the
+    operand's end is not written back."""
+    bc = x_ref.shape[1]
+    if bc <= CHUNK_COLS:
+        o_ref[...] = _mix(x_ref[...], t, c)
+        return
+
+    def body(k, carry):
+        cols = pl.ds(pl.multiple_of(k * CHUNK_COLS, CHUNK_COLS), CHUNK_COLS)
+        o_ref[:, cols] = _mix(x_ref[:, cols], t, c)
+        return carry
+
+    jax.lax.fori_loop(0, bc // CHUNK_COLS, body, 0)
+
+
+def _add_gram(x_ref, g_ref, j, width, n_blocks):
+    """``g_ref += `` the Gram of grid block ``j`` of an operand ``width``
+    columns wide; only the last block can be ragged."""
+    bc = x_ref.shape[1]
+    last = width - (n_blocks - 1) * bc
+    if last == bc:
+        g_ref[...] += _block_gram(x_ref, bc)
+        return
+
+    @pl.when(j < n_blocks - 1)
+    def _whole():
+        g_ref[...] += _block_gram(x_ref, bc)
+
+    @pl.when(j == n_blocks - 1)
+    def _ragged():
+        g_ref[...] += _block_gram(x_ref, last)
 
 
 def _row_vec(c, R):
@@ -190,7 +298,8 @@ def _row_vec(c, R):
 
 
 def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
-                        o_ref, r_ref, g_ref, g_acc, coef_scr, *, eps):
+                        o_ref, r_ref, g_ref, g_acc, coef_scr, *, eps, width,
+                        n_blocks):
     phase = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -200,14 +309,7 @@ def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
 
     @pl.when(phase == 0)
     def _gram():
-        x = x_ref[...]
-        # Block-centered Gram: shifting every column by its row-0 value is
-        # free (loaded block is in VMEM) and removes the catastrophic
-        # cancellation of an uncentered x @ x.T — entries are O(spread^2),
-        # not O(||x||^2). Any zero-sum quadratic form of G is exact.
-        e = x - x[0:1, :]
-        g_acc[...] += jnp.dot(e, e.T, precision=_HIGHEST,
-                              preferred_element_type=jnp.float32)
+        _add_gram(x_ref, g_acc, j, width, n_blocks)
         # o_ref is not written: its block index stays (0, 0) through
         # phase 0 and phase 1's first step, so nothing is written back
         # until phase 1 has filled block 0
@@ -237,17 +339,11 @@ def _fused_round_kernel(x_ref, t_ref, c0_ref, c1_ref,
         # reproduces the target bitwise (hard pull), and a huge |c| scales
         # a difference of nearby values — exact in every regime, unlike a
         # single W @ x GEMM whose rounding grows with |c| * ||x||
-        x = x_ref[...]
-        c = coef_scr[...]
-        tx = jnp.dot(t_ref[...], x, precision=_HIGHEST,
-                     preferred_element_type=jnp.float32)
-        o_ref[...] = tx + (1.0 - c) * (x - tx)
+        _mix_block(x_ref, t_ref[...], coef_scr[...], o_ref)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("eps", "block_cols", "interpret"))
-def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
-                interpret=None):
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def fused_round(flat, T, c0, c1, *, eps=1e-12, interpret=None):
     """One consensus stage over the flat (R, n) worker matrix, fused.
 
     Per row i: ``r_i = ||x_i - T_i @ x||``, ``coef_i = c0_i + c1_i /
@@ -256,20 +352,20 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
     row-stochastic (rows sum to 1); that makes every distance a zero-sum
     quadratic form of the Gram, which the block-centering computes exactly.
 
-    Single ``pallas_call``, grid (2, n_blocks): phase 0 accumulates the
-    Gram (one HBM read of x), phase 1 applies the mixing (one more read +
-    the only write). The row block is R itself; the output aliases the
-    input, so under a donated caller the stage updates the view in place.
-    Returns ``(out (R, n) f32, r (R,), G (R, R))`` — G is the
-    *block-centered* Gram: only zero-sum quadratic forms of it are
-    meaningful (see repro/core/engine.py).
+    Single ``pallas_call``, grid (2, n_blocks) over the column blocks of
+    ``kernel_block``: phase 0 accumulates the Gram (one HBM read of x),
+    phase 1 applies the mixing (one more read + the only write). The row
+    block is R itself; the output aliases the input, so under a donated
+    caller the stage updates the view in place. Returns ``(out (R, n) f32,
+    r (R,), G (R, R))`` — G is the *block-centered* Gram: only zero-sum
+    quadratic forms of it are meaningful (see repro/core/engine.py).
     """
     R, n = flat.shape
-    bc = _col_block(n, block_cols)
-    width = padded_width(n, block_cols)
+    bc, n_blocks = kernel_block(R, n)
     out, r, G = pl.pallas_call(
-        functools.partial(_fused_round_kernel, eps=eps),
-        grid=(2, width // bc),
+        functools.partial(_fused_round_kernel, eps=eps, width=n,
+                          n_blocks=n_blocks),
+        grid=(2, n_blocks),
         in_specs=[
             pl.BlockSpec((R, bc), lambda p, j: (0, j)),
             pl.BlockSpec((R, R), lambda p, j: (0, 0)),
@@ -284,7 +380,7 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
             pl.BlockSpec((R, R), lambda p, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, width), jnp.float32),
+            jax.ShapeDtypeStruct((R, n), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, R), jnp.float32),
         ],
@@ -293,11 +389,12 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
             pltpu.VMEM((R, 1), jnp.float32),   # per-row coefficients
         ],
         input_output_aliases={0: 0},
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(interpret),
         name="fused_round",
-    )(_pad_cols(flat, width), T.astype(jnp.float32), _row_vec(c0, R),
+    )(flat.astype(jnp.float32), T.astype(jnp.float32), _row_vec(c0, R),
       _row_vec(c1, R))
-    return (out if width == n else out[:, :n]), r[:, 0], G
+    return out, r[:, 0], G
 
 
 # ---------------------------------------------------------------------------
@@ -316,67 +413,62 @@ def fused_round(flat, T, c0, c1, *, eps=1e-12, block_cols=2048,
 # only way the Gram is ever read.
 
 
-def _partial_gram_kernel(x_ref, g_ref):
-    @pl.when(pl.program_id(0) == 0)
+def _partial_gram_kernel(x_ref, g_ref, *, width, n_blocks):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
     def _init():
         g_ref[...] = jnp.zeros_like(g_ref)
 
-    x = x_ref[...]
-    e = x - x[0:1, :]                      # block-centered (see fused_round)
-    g_ref[...] += jnp.dot(e, e.T, precision=_HIGHEST,
-                          preferred_element_type=jnp.float32)
+    _add_gram(x_ref, g_ref, j, width, n_blocks)   # as in fused_round
 
 
 def _mix_kernel(c_ref, x_ref, t_ref, o_ref):
-    x = x_ref[...]
-    tx = jnp.dot(t_ref[...], x, precision=_HIGHEST,
-                 preferred_element_type=jnp.float32)
-    o_ref[...] = tx + (1.0 - c_ref[...]) * (x - tx)
+    _mix_block(x_ref, t_ref[...], c_ref[...], o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
-def partial_gram(flat, *, block_cols=2048, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def partial_gram(flat, *, interpret=None):
     """Block-centered Gram of a (R, n_local) column shard — phase 0 of
     ``fused_round`` as its own kernel. Zero-sum quadratic forms of the
     summed per-shard outputs equal those of the full-width Gram."""
     R, n = flat.shape
-    bc = _col_block(n, block_cols)
-    width = padded_width(n, block_cols)
+    bc, n_blocks = kernel_block(R, n)
     return pl.pallas_call(
-        _partial_gram_kernel,
-        grid=(width // bc,),
+        functools.partial(_partial_gram_kernel, width=n, n_blocks=n_blocks),
+        grid=(n_blocks,),
         in_specs=[pl.BlockSpec((R, bc), lambda j: (0, j))],
         out_specs=pl.BlockSpec((R, R), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, R), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(interpret),
         name="partial_gram",
-    )(_pad_cols(flat, width))
+    )(flat.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
-def mix_shard(flat, T, coef, *, block_cols=2048, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mix_shard(flat, T, coef, *, interpret=None):
     """Apply ``out_i = x_i + coef_i (T_i x - x_i)`` to a (R, n_local)
     column shard with PRECOMPUTED coefficients — phase 1 of ``fused_round``
     (same uniform gap form, exact at c = 1 and for huge |c|). The output
     aliases the input, like ``fused_round``."""
     R, n = flat.shape
-    bc = _col_block(n, block_cols)
-    width = padded_width(n, block_cols)
-    out = pl.pallas_call(
+    bc, n_blocks = kernel_block(R, n)
+    return pl.pallas_call(
         _mix_kernel,
-        grid=(width // bc,),
+        grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((R, 1), lambda j: (0, 0)),
             pl.BlockSpec((R, bc), lambda j: (0, j)),
             pl.BlockSpec((R, R), lambda j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((R, bc), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((R, width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, n), jnp.float32),
         input_output_aliases={1: 0},
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(interpret),
         name="mix_shard",
-    )(_row_vec(coef, R), _pad_cols(flat, width), T.astype(jnp.float32))
-    return out if width == n else out[:, :n]
+    )(_row_vec(coef, R), flat.astype(jnp.float32), T.astype(jnp.float32))
 
 
 def _coef_from_gram(T, c0, c1, G, eps):
@@ -392,8 +484,7 @@ def _coef_from_gram(T, c0, c1, G, eps):
     return r, coef
 
 
-def mix_from_gram(flat, T, c0, c1, G, *, eps=1e-12, block_cols=2048,
-                  interpret=None):
+def mix_from_gram(flat, T, c0, c1, G, *, eps=1e-12, interpret=None):
     """Gather-free mixing epilogue: one consensus stage whose column
     contraction ALREADY happened — ``G`` is a completed (block-centered or
     plain) Gram, e.g. the psum'd sum of per-chunk ``partial_gram`` calls
@@ -405,13 +496,12 @@ def mix_from_gram(flat, T, c0, c1, G, *, eps=1e-12, block_cols=2048,
     ``fused_round``.
     """
     r, coef = _coef_from_gram(T, c0, c1, G, eps)
-    out = mix_shard(flat, T, coef, block_cols=block_cols,
-                    interpret=interpret)
+    out = mix_shard(flat, T, coef, interpret=interpret)
     return out, r, G
 
 
 def fused_round_sharded(flat, T, c0, c1, *, axis, eps=1e-12,
-                        block_cols=2048, interpret=None):
+                        interpret=None):
     """``fused_round`` for a column shard under shard_map.
 
     ``flat`` is the local (R, n_local) shard; ``axis`` names the mesh
@@ -423,9 +513,8 @@ def fused_round_sharded(flat, T, c0, c1, *, axis, eps=1e-12,
     block-centered Gram: zero-sum forms only). Must be called inside a
     ``shard_map`` that binds ``axis``.
     """
-    G = partial_gram(flat, block_cols=block_cols, interpret=interpret)
+    G = partial_gram(flat, interpret=interpret)
     G = jax.lax.psum(G, axis)
     r, coef = _coef_from_gram(T, c0, c1, G, eps)
-    out = mix_shard(flat, T, coef, block_cols=block_cols,
-                    interpret=interpret)
+    out = mix_shard(flat, T, coef, interpret=interpret)
     return out, r, G

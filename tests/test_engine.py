@@ -13,6 +13,8 @@ from repro.configs import DPPFConfig
 from repro.core import consensus, pullpush as pp
 from repro.core.engine import ConsensusEngine
 from repro.kernels.pullpush import fused_round, fused_round_ref
+from repro.kernels.pullpush import pullpush as pk
+from repro.kernels.pullpush.pullpush import kernel_block
 
 METRIC_KEYS = {"consensus_dist", "pre_dist", "pull_force", "push_force"}
 
@@ -250,7 +252,19 @@ def test_trainer_flat_engine_easgd_and_lsgd_run():
 # fused kernel vs oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [(4, 300), (8, 4097), (3, 128)])
+def _widest_block(R):
+    """The kernel's column block at R rows for an operand wider than it."""
+    return kernel_block(R, 1 << 40)[0]
+
+
+# small widths; a ragged last block (two whole blocks and three lane tiles)
+# at R = 2, 4 and 5 (an aux row); a width of exactly one block
+KERNEL_SHAPES = [(4, 300), (8, 4097), (3, 128)] + [
+    (R, 2 * _widest_block(R) + 3 * 128) for R in (2, 4, 5)] + [
+    (4, _widest_block(4))]
+
+
+@pytest.mark.parametrize("shape", KERNEL_SHAPES)
 def test_fused_round_kernel_vs_ref(shape):
     R, n = shape
     key = jax.random.PRNGKey(R * n)
@@ -259,12 +273,51 @@ def test_fused_round_kernel_vs_ref(shape):
     T = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (R, R)))
     c0 = jnp.linspace(0.1, 0.5, R)
     c1 = jnp.linspace(-0.4, -0.1, R)
-    got, r_got, G = fused_round(flat, T, c0, c1, block_cols=256)
+    # interpreted, a block read past the operand's end holds NaN: an
+    # unmasked ragged block would poison the Gram
+    got, r_got, G = fused_round(flat, T, c0, c1)
     want, r_want = fused_round_ref(flat, T, c0, c1)
     np.testing.assert_allclose(np.asarray(r_got), np.asarray(r_want),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+WIDTHS = [128, 1000, 2048, 2176, 10240, 262144, 262144 + 128, 238_563_328,
+          532_236_288]
+
+
+@pytest.mark.parametrize("R", range(1, 9))
+def test_kernel_block_fits_fast_memory(R):
+    """The column block: lane-aligned, no wider than the operand, its four
+    pipelined (R, bc) fp32 buffers (rows in whole 8-row tiles) inside the
+    budget, and the blocks cover the width with at most one ragged."""
+    for width in WIDTHS:
+        bc, n_blocks = kernel_block(R, width)
+        assert bc <= width
+        assert bc % 128 == 0 or (n_blocks == 1 and bc == width)
+        assert 4 * 8 * -(-R // 8) * 4 * bc <= pk.VMEM_BLOCK_BUDGET
+        assert (n_blocks - 1) * bc < width <= n_blocks * bc
+        if width > pk.CHUNK_COLS:
+            assert bc % pk.CHUNK_COLS == 0
+    # a grid step's fixed cost is paid per block: the yi-6b view takes
+    # hundreds of blocks, not the 116,486 of 2048 columns
+    assert kernel_block(R, 238_563_328)[1] <= 1000
+
+
+def test_padded_width_rounds_to_2048():
+    """The engine's view stays padded to a multiple of 2048 columns (the
+    benchmark's byte count reads that width), whatever block the kernels
+    walk it in."""
+    assert pk.VIEW_QUANTUM == 2048
+    assert pk.padded_width(238_563_328) == 238_563_328
+    assert pk.padded_width(238_563_328 - 5) == 238_563_328
+    assert pk.padded_width(4097) == 6144
+    assert pk.padded_width(300) == 300
+    stacked = {"a": jnp.zeros((2, 3000)), "b": jnp.zeros((2, 100))}
+    eng = ConsensusEngine.from_stacked(stacked, use_kernel=True)
+    assert (eng.layout.n, eng.layout.width) == (3100, 4096)
+    assert eng.flatten(stacked).shape == (2, 4096)
 
 
 @pytest.mark.parametrize("mode", ["precise", "kernel"])
@@ -350,15 +403,16 @@ def test_pullpush_fused_exact_near_consensus(use_kernel):
 def test_fused_round_centered_gram_is_cancellation_safe():
     """Workers clustered far from the origin: the kernel's block-centered
     Gram keeps relative distance error ~1e-6 where a naive uncentered
-    x @ x.T Gram loses several digits."""
+    x @ x.T Gram loses several digits, summed over many chunks and over
+    blocks with a ragged last one."""
     key = jax.random.PRNGKey(2)
-    n, M = 4096, 4
+    M = 4
+    n = 2 * _widest_block(M) + 3 * 128
     base = jax.random.normal(key, (n,)) * 3.0 + 5.0
     flat = base[None] + 0.05 * jax.random.normal(
         jax.random.fold_in(key, 1), (M, n))
     T = jnp.full((M, M), 1.0 / M)
-    _, r, _ = fused_round(flat, T, jnp.zeros(M), jnp.zeros(M),
-                          block_cols=512)
+    _, r, _ = fused_round(flat, T, jnp.zeros(M), jnp.zeros(M))
     f64 = np.asarray(flat, np.float64)
     r_true = np.sqrt(((f64 - f64.mean(0)) ** 2).sum(1))
     np.testing.assert_allclose(np.asarray(r), r_true, rtol=1e-5)

@@ -207,7 +207,7 @@ def test_doublebuf_chunked_gram_within_fp32_bounds():
     stacked = {"w": jax.random.normal(key, (4, 1000)) * 2.0 + 1.0}
     T = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (4, 4)))
     for patch in ({}, {"precise": True},
-                  {"use_kernel": True, "interpret": True, "block_cols": 64}):
+                  {"use_kernel": True, "interpret": True}):
         eng = ConsensusEngine.from_stacked(stacked, use_kernel=False,
                                            interpret=True)
         eng = dataclasses.replace(eng, **patch)
@@ -364,27 +364,39 @@ def test_overlap_converges_close_to_exact():
 # split kernel phases: partial Grams add across column shards
 # ---------------------------------------------------------------------------
 
-def test_partial_gram_plus_mix_match_fused_round():
+def _shard_widths():
+    """Column-shard widths: small; two blocks, the last ragged, at R = 2,
+    4 and 5; exactly one block."""
+    from repro.kernels.pullpush.pullpush import kernel_block
+
+    def block(R):
+        return kernel_block(R, 1 << 40)[0]
+    return [(5, 250)] + [(R, block(R) + 256) for R in (2, 4, 5)] + [
+        (4, block(4))]
+
+
+@pytest.mark.parametrize("shape", _shard_widths())
+def test_partial_gram_plus_mix_match_fused_round(shape):
     from repro.kernels.pullpush import (
         fused_round, fused_round_ref, mix_shard, partial_gram,
     )
     key = jax.random.PRNGKey(1)
-    R, n = 5, 1000
+    R, n_local = shape
+    n = 4 * n_local
     flat = jax.random.normal(key, (R, n)) * 2.0 + 1.0
     T = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (R, R)))
     c0 = jnp.linspace(0.1, 0.5, R)
     c1 = jnp.linspace(-0.4, -0.1, R)
     want, r_want = fused_round_ref(flat, T, c0, c1)
-    got_fused, r_fused, _ = fused_round(flat, T, c0, c1, block_cols=256)
+    got_fused, r_fused, _ = fused_round(flat, T, c0, c1)
 
     # simulate 4 column shards: psum == plain sum of the partial Grams
     shards = jnp.split(flat, 4, axis=1)
-    G = sum(partial_gram(s, block_cols=256) for s in shards)
+    G = sum(partial_gram(s) for s in shards)
     V = jnp.eye(R) - T
     r = jnp.sqrt(jnp.maximum(jnp.sum((V @ G) * V, axis=1), 0.0))
     coef = c0 + c1 / jnp.maximum(r, 1e-12)
-    out = jnp.concatenate(
-        [mix_shard(s, T, coef, block_cols=256) for s in shards], axis=1)
+    out = jnp.concatenate([mix_shard(s, T, coef) for s in shards], axis=1)
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_want), rtol=1e-4)
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_fused), rtol=1e-4)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -395,14 +407,17 @@ def test_partial_gram_plus_mix_match_fused_round():
 
 def test_partial_gram_centered_cancellation_safe():
     """Per-shard block-centering must survive the cross-shard sum: workers
-    clustered far from the origin keep ~1e-5 relative distance accuracy."""
+    clustered far from the origin keep ~1e-5 relative distance accuracy,
+    with each shard two blocks wide, the last ragged."""
     from repro.kernels.pullpush import partial_gram
+    from repro.kernels.pullpush.pullpush import kernel_block
     key = jax.random.PRNGKey(2)
-    n, M = 4096, 4
+    M = 4
+    n = 2 * (kernel_block(M, 1 << 40)[0] + 3 * 128)
     base = jax.random.normal(key, (n,)) * 3.0 + 5.0
     flat = base[None] + 0.05 * jax.random.normal(
         jax.random.fold_in(key, 1), (M, n))
-    G = sum(partial_gram(s, block_cols=512) for s in jnp.split(flat, 2, 1))
+    G = sum(partial_gram(s) for s in jnp.split(flat, 2, 1))
     T = jnp.full((M, M), 1.0 / M)
     V = jnp.eye(M) - T
     r = np.sqrt(np.maximum(np.asarray(jnp.sum((V @ G) * V, axis=1)), 0.0))
@@ -686,8 +701,7 @@ print("parity OK")
 
 # kernel path (interpret mode): split phases + psum epilogue under shard_map
 dcfg = DPPFConfig(alpha=0.2, lam=0.4, tau=tau, engine="flat")
-dp, dm = run_pair(dcfg, engine_patch={"use_kernel": True, "interpret": True,
-                                      "block_cols": 64})
+dp, dm = run_pair(dcfg, engine_patch={"use_kernel": True, "interpret": True})
 assert dp < 2e-5 and dm < 1e-4, ("kernel", dp, dm)
 print("kernel OK")
 
@@ -798,8 +812,7 @@ base = dict(alpha=0.2, lam=0.4, tau=tau, engine="flat",
 d_s1 = DPPFConfig(overlap="staleness1", **base)
 d_db = DPPFConfig(overlap="doublebuf", overlap_chunks=2, **base)
 dp, ds, dm = run_pair(hmesh, hplan, d_s1, d_db,
-                      engine_patch={"use_kernel": True, "interpret": True,
-                                    "block_cols": 32})
+                      engine_patch={"use_kernel": True, "interpret": True})
 assert dp < 2e-5 and dm < 1e-4, ("kernel", dp, ds, dm)
 print("doublebuf kernel OK")
 
@@ -912,8 +925,7 @@ print("hier parity OK")
 
 # kernel path: partial_gram/mix_shard + psum epilogue over BOTH axes
 dcfg = DPPFConfig(alpha=0.2, lam=0.4, tau=tau, engine="flat")
-dp, dm = run_pair(dcfg, engine_patch={"use_kernel": True, "interpret": True,
-                                      "block_cols": 32})
+dp, dm = run_pair(dcfg, engine_patch={"use_kernel": True, "interpret": True})
 assert dp < 2e-5 and dm < 1e-4, ("kernel", dp, dm)
 print("hier kernel OK")
 

@@ -32,6 +32,9 @@ from repro.kernels.pullpush import pullpush as pk
 # the yi-6b chip share chip_smoke.py trains (1 layer, 8000 vocab rows)
 N = 238_563_328
 R = 4
+# the benchmark's views: yi-6b with 4 workers, internlm2-20b with 2, and
+# the yi-6b one with an aux row (easgd, parle)
+VIEWS = [(4, N), (2, 532_236_288), (5, N)]
 F32 = jnp.float32
 
 
@@ -64,7 +67,7 @@ def _sds(shape, sharding):
     return jax.ShapeDtypeStruct(shape, F32, sharding=sharding)
 
 
-def _kernel_call(name):
+def _kernel_call(name, R=R, N=N):
     """(function, donated arg index or None, arg shapes) per kernel."""
     if name == "fused_round":
         return (lambda x, T, c0, c1: pk.fused_round(
@@ -77,14 +80,17 @@ def _kernel_call(name):
             [(R, N), (R, R), (R,)])
 
 
+@pytest.mark.parametrize("view", VIEWS, ids=lambda v: f"R{v[0]}")
 @pytest.mark.parametrize("name", ["fused_round", "partial_gram",
                                   "mix_shard"])
-def test_consensus_kernel_compiles_for_v5e(name, one_chip):
-    """Each consensus kernel compiles (not interpreted) at the smoke's n,
-    and its program needs no temporary beyond one (R, n) fp32 buffer plus
-    64 MiB: the kernels pad no rows, copy no view, and write their output
-    over the donated input."""
-    fn, donate, shapes = _kernel_call(name)
+def test_consensus_kernel_compiles_for_v5e(name, view, one_chip):
+    """Each consensus kernel compiles (not interpreted) at the benchmark's
+    widths, with a ragged last column block, and its program needs no
+    temporary beyond one (R, n) fp32 buffer plus 64 MiB: the kernels pad
+    no rows, copy no view, and write their output over the donated
+    input."""
+    R, N = view
+    fn, donate, shapes = _kernel_call(name, R, N)
     jitted = jax.jit(fn, donate_argnums=() if donate is None else donate)
     compiled = jitted.lower(*[_sds(s, one_chip) for s in shapes]).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -97,6 +103,25 @@ def test_consensus_kernel_compiles_for_v5e(name, one_chip):
     if donate is not None:
         # the (R, n) result is written into the donated view
         assert mem.alias_size_in_bytes >= R * N * 4, mem.alias_size_in_bytes
+
+
+def test_fused_round_code_does_not_grow_with_block(one_chip):
+    """The kernel walks its wide column block a chunk at a time, so its
+    program is as large at the yi-6b view as at one chunk. Mosaic unrolls
+    a kernel body over the block's vector registers: a body over a whole
+    (4, 131072) block made a 2.6 MB program and took 8.7-11.1 s to compile
+    on a v5e host, against 1 s or less chunked: the cost of a process
+    whose compile cache misses."""
+    from jax.experimental.serialize_executable import serialize
+
+    def program_bytes(n):
+        fn, donate, shapes = _kernel_call("fused_round", 4, n)
+        compiled = jax.jit(fn, donate_argnums=donate).lower(
+            *[_sds(s, one_chip) for s in shapes]).compile()
+        return len(serialize(compiled)[0])
+
+    assert pk.kernel_block(4, N)[0] >= 32 * pk.CHUNK_COLS
+    assert program_bytes(N) < 2 * program_bytes(pk.CHUNK_COLS)
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
