@@ -1,63 +1,46 @@
 """The plain reference of a training cell, and the weights both sides start from.
 
-Nothing here imports the program under test. ``make_weights`` draws the
-weights from the seed, in bfloat16 (the type the configurations state), as
-one jitted call; the benchmark hands the same tree to the program as its
-initial model, so both sides start from the same numbers without the
-reference reading anything the program made.
+Nothing here imports the program under test. A configuration file names
+its architecture module under ``"reference"`` (``bench.dense``, the dense
+decoder, where it names none); ``architecture`` resolves it, and every
+other file of the benchmark reaches architecture code through it. What
+follows is shared by all architectures.
+
+``make_weights`` draws the weights from the seed, in the type the
+configuration states, as one jitted call, leaf by leaf with the
+architecture's ``init_leaf``; the benchmark hands the same tree to the
+program as its initial model, so both sides start from the same numbers
+without the reference reading anything the program made.
 
 ``ReferenceRun`` follows the first rounds of a DPPF run in plain
-``jax.numpy``: a decoder layer as the program's model computes it
-(pre-norm residual blocks, zero-centred RMSNorm gains ``x * (1 + w)``,
-embeddings scaled by ``sqrt(hidden_size)``, rotary embeddings on halves,
-grouped-query causal attention, SwiGLU, an untied head, mean token
-cross-entropy), SGD with momentum and the weight decay added to the
-gradient (``g + wd * p``), a cosine learning rate, and the paper's Eq. 5 consensus
-over the workers: ``r_i = ||x_i - mean||``, ``c_i = alpha - lam_t / r_i``,
-``x_i <- mean + (1 - c_i) (x_i - mean)``. The first two conventions
-(zero-centred gains, scaled embeddings) are the program's and depart from
-the published Yi and InternLM2 descriptions; PERF.md lists them.
-
-Every matrix product runs at ``Precision.HIGHEST`` in float32. With
-``precision="float8_e4m3fn"`` every matrix product's operands are rounded
-to float8 first, forward and backward: the control that must fail.
+``jax.numpy`` on the architecture's ``loss_fn``: SGD with momentum and the
+weight decay added to the gradient (``g + wd * p``), a cosine learning
+rate, and the paper's Eq. 5 consensus over the workers:
+``r_i = ||x_i - mean||``, ``c_i = alpha - lam_t / r_i``,
+``x_i <- mean + (1 - c_i) (x_i - mean)``.
 """
 from __future__ import annotations
 
+import importlib
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
 EPS = 1e-12
-Q_CHUNK = 512
+DEFAULT_ARCHITECTURE = "bench.dense"
 
 
-def dims(cfg):
-    """(d, heads, kv heads, head dim, ffn, vocab, layers) of a config file."""
-    d = cfg["hidden_size"]
-    nq = cfg["num_attention_heads"]
-    return (d, nq, cfg["num_key_value_heads"], d // nq,
-            cfg["intermediate_size"], cfg["vocab_size"],
-            cfg["num_hidden_layers"])
-
-
-def param_shapes(cfg):
-    """The parameter tree the program's decoder takes, as shapes."""
-    d, nq, nkv, hd, f, V, L = dims(cfg)
-    return {
-        "blocks": {"stack": {
-            "attn": {"wk": (L, d, nkv * hd), "wo": (L, nq * hd, d),
-                     "wq": (L, d, nq * hd), "wv": (L, d, nkv * hd)},
-            "ln1": (L, d), "ln2": (L, d),
-            "mlp": {"w_down": (L, f, d), "w_gate": (L, d, f),
-                    "w_up": (L, d, f)}}},
-        "embed": (V, d),
-        "final_norm": (d,),
-        "lm_head": (d, V),
-    }
+def architecture(cfg):
+    """The architecture module of a configuration file: the one it names
+    under ``"reference"``, a module inside the benchmark, else
+    ``bench.dense``."""
+    name = cfg.get("reference", DEFAULT_ARCHITECTURE)
+    if not name.startswith("bench."):
+        raise ValueError(f"{cfg.get('name')}: reference {name!r} is not a "
+                         "module of the benchmark (bench.<module>)")
+    return importlib.import_module(name)
 
 
 def seed_key(seed):
@@ -67,21 +50,11 @@ def seed_key(seed):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _leaf_init(key, path, shape):
-    name = path[-1]
-    if name in ("ln1", "ln2", "final_norm"):
-        return jnp.zeros(shape, jnp.bfloat16)
-    if name in ("embed", "lm_head"):
-        std = 0.02
-    else:
-        std = shape[-2] ** -0.5          # fan-in scaling
-    return (jax.random.normal(key, shape, jnp.float32) * std) \
-        .astype(jnp.bfloat16)
-
-
 def make_weights(cfg):
-    """A jitted ``key -> tree`` of bfloat16 weights for ``cfg``."""
-    shapes = param_shapes(cfg)
+    """A jitted ``key -> tree`` of the architecture's weights for ``cfg``:
+    leaf ``i`` (in the tree's order) from ``fold_in(key, i)``."""
+    arch = architecture(cfg)
+    shapes = arch.param_shapes(cfg)
     paths = [tuple(k.key for k in p) for p, _ in
              jax.tree_util.tree_flatten_with_path(
                  shapes, is_leaf=lambda x: isinstance(x, tuple))[0]]
@@ -93,86 +66,9 @@ def make_weights(cfg):
     @jax.jit
     def init(key):
         return jax.tree_util.tree_unflatten(treedef, [
-            _leaf_init(jax.random.fold_in(key, i), path, shape)
+            arch.init_leaf(jax.random.fold_in(key, i), path, shape)
             for i, (path, shape) in enumerate(zip(paths, leaves))])
     return init
-
-
-# ---------------------------------------------------------------------------
-# The forward pass
-# ---------------------------------------------------------------------------
-
-def _quantizer(precision):
-    if precision == "float32":
-        return lambda x: x
-    dt = jnp.dtype(precision)
-    return lambda x: x.astype(dt).astype(jnp.float32)
-
-
-def _rms(x, w, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + w)
-
-
-def _rope(x, theta):
-    """Rotary embeddings on the two halves of each head. x: (B, S, H, hd)."""
-    S, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = np.exp(-np.log(theta) * np.arange(half, dtype=np.float64) / half)
-    ang = np.arange(S, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def loss_fn(params, tokens, labels, cfg, precision="float32"):
-    """Mean next-token cross-entropy of one worker's (B, S) batch."""
-    d, nq, nkv, hd, f, V, L = dims(cfg)
-    eps = cfg["rms_norm_eps"]
-    q8 = _quantizer(precision)
-
-    def mm(a, b):
-        return jnp.matmul(q8(a), q8(b), precision=HIGHEST)
-
-    B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0) * math.sqrt(d)
-    stack = params["blocks"]["stack"]
-    causal = np.tril(np.ones((S, S), bool))
-    for layer in range(L):
-        p = jax.tree.map(lambda a: a[layer], stack)
-        h = _rms(x, p["ln1"], eps)
-        q = _rope(mm(h, p["attn"]["wq"]).reshape(B, S, nq, hd),
-                  cfg["rope_theta"])
-        k = _rope(mm(h, p["attn"]["wk"]).reshape(B, S, nkv, hd),
-                  cfg["rope_theta"])
-        v = mm(h, p["attn"]["wv"]).reshape(B, S, nkv, hd)
-        k = jnp.repeat(k, nq // nkv, axis=2)
-        v = jnp.repeat(v, nq // nkv, axis=2)
-
-        @jax.checkpoint
-        def attend(qc, mask):
-            s = jnp.einsum("bqhd,bkhd->bhqk", q8(qc), q8(k),
-                           precision=HIGHEST) / math.sqrt(hd)
-            s = jnp.where(mask[None, None], s, -jnp.inf)
-            w = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", q8(w), q8(v),
-                              precision=HIGHEST)
-
-        # queries in blocks, recomputed in the backward pass, so that one
-        # (heads, S, S) score matrix never has to be held whole
-        o = jnp.concatenate(
-            [attend(q[:, a:a + Q_CHUNK], causal[a:a + Q_CHUNK])
-             for a in range(0, S, Q_CHUNK)], axis=1)
-        x = x + mm(o.reshape(B, S, nq * hd), p["attn"]["wo"])
-        h = _rms(x, p["ln2"], eps)
-        g = jax.nn.silu(mm(h, p["mlp"]["w_gate"]))
-        x = x + mm(g * mm(h, p["mlp"]["w_up"]), p["mlp"]["w_down"])
-    x = _rms(x, params["final_norm"], eps)
-    logits = mm(x, params["lm_head"])
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked)
 
 
 # ---------------------------------------------------------------------------
@@ -211,12 +107,13 @@ class ReferenceRun:
         self.total_steps = traffic["plan_rounds"] * self.tau
         wd, mom = train["weight_decay"], train["momentum"]
         half = fault == "half_batch"
+        loss = architecture(cfg).loss_fn
 
         def one_loss(p, tok, lab):
             if half:
                 s = tok.shape[-1] // 2
                 tok, lab = tok[..., :s], lab[..., :s]
-            return loss_fn(p, tok, lab, cfg, precision)
+            return loss(p, tok, lab, cfg, precision)
 
         def local(p, mu, tok, lab, lrs):
             losses = []

@@ -87,7 +87,12 @@ def load_cell(name, spec_path=ROOT / "BENCHMARK.json"):
 
 @dataclasses.dataclass
 class RunRecord:
-    """What the metric readers see (``bench/metrics``)."""
+    """What the metric readers see (``bench/metrics``): with the cell's
+    configuration and traffic files, a reader can count a kernel's work
+    from shapes through the architecture module
+    (``bench.reference.architecture(ctx.cfg)``)."""
+    cfg: dict
+    traffic: dict
     chips: int
     tau: int
     setup_s: float
@@ -104,23 +109,18 @@ class RunRecord:
 
 def program_config(cfg):
     """The program's ModelConfig for a configuration file: the named
-    published architecture with the file's sizes."""
+    published architecture with the fields the file's architecture module
+    fixes (``program_fields``). Where the file's ``program`` block names a
+    cut beyond ``arch`` (``layers``, ``vocab``, ...), the result must be the
+    program's own ``cut`` of the published architecture by those keys."""
+    from bench import reference
     from repro.configs import cut, get_arch
     prog = cfg["program"]
-    d, nq = cfg["hidden_size"], cfg["num_attention_heads"]
-    sizes = dict(n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=nq,
-                 n_kv_heads=cfg["num_key_value_heads"], head_dim=d // nq,
-                 d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-                 rope_theta=float(cfg["rope_theta"]),
-                 norm_eps=float(cfg["rms_norm_eps"]),
-                 qkv_bias=bool(cfg["attention_bias"]),
-                 tie_embeddings=bool(cfg["tie_word_embeddings"]),
-                 act=cfg["hidden_act"], dtype=cfg["torch_dtype"])
-    mcfg = dataclasses.replace(get_arch(prog["arch"]), **sizes)
-    if "layers" in prog:
-        # a published cut: the file must be the launcher's cut exactly
-        want = cut(get_arch(prog["arch"]), layers=prog["layers"],
-                   vocab=prog["vocab"])
+    fields = reference.architecture(cfg).program_fields(cfg)
+    mcfg = dataclasses.replace(get_arch(prog["arch"]), **fields)
+    cut_by = {k: v for k, v in prog.items() if k != "arch"}
+    if cut_by:
+        want = cut(get_arch(prog["arch"]), **cut_by)
         if dataclasses.replace(mcfg, name=want.name) != want:
             raise ValueError(f"{cfg['name']}: the file's sizes differ from "
                              f"the program's cut of {prog['arch']}")
@@ -194,9 +194,19 @@ def execute(cell, seed, seconds, trace, devices, *, fault=None, log=None):
     clock = RoundClock.from_config(dcfg, base_lr=tr["lr"],
                                    total_steps=traffic["plan_rounds"] * tau)
 
-    # the weights: one jitted call from the seed, in bf16; a host copy of
-    # the flat initial view is kept for the change after the check rounds
+    # the weights: one jitted call from the seed, in bf16, of the tree the
+    # program's own model takes (the same leaves in the same order, of the
+    # same shapes and types); a host copy of the flat initial view is kept
+    # for the change after the check rounds
+    arch = reference.architecture(cfg)
     make_w = reference.make_weights(cfg)
+    own = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    ref_tree = jax.eval_shape(make_w, reference.seed_key(seed))
+    if jax.tree.structure(own) != jax.tree.structure(ref_tree) or [
+            (a.shape, a.dtype) for a in jax.tree.leaves(own)] != [
+            (a.shape, a.dtype) for a in jax.tree.leaves(ref_tree)]:
+        raise ValueError("the program's model does not take the "
+                         "reference's leaves in the reference's order")
     w0 = make_w(reference.seed_key(seed))
     x0_host = np.concatenate([np.asarray(a).reshape(-1)
                               for a in jax.tree.leaves(w0)])
@@ -208,7 +218,7 @@ def execute(cell, seed, seconds, trace, devices, *, fault=None, log=None):
                                 round=jnp.asarray(K0, state.round.dtype))
     lay = state.engine.layout
     shapes = [tuple(s) for s in jax.tree.leaves(
-        reference.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))]
+        arch.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))]
     if [tuple(s) for s in lay.shapes] != shapes:
         raise ValueError("the program's flat view does not hold the "
                          "reference's leaves in the reference's order")
@@ -329,7 +339,8 @@ def execute(cell, seed, seconds, trace, devices, *, fault=None, log=None):
     peaks = json.loads((BENCH / "peaks.json").read_text())
     kind = devices[0].device_kind
     rec = RunRecord(
-        chips=cell.chips, tau=tau, setup_s=setup_s, window_s=window_s,
+        cfg=cfg, traffic=traffic, chips=cell.chips, tau=tau,
+        setup_s=setup_s, window_s=window_s,
         window_tokens=n_window * tau * M * B * S, round_s=round_s,
         flops_per_token=flops.flops_per_token(cfg, S),
         consensus_bytes_per_round=flops.consensus_bytes(lay.R, lay.width),

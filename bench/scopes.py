@@ -24,7 +24,9 @@ fields below), with no dependency beyond the standard library.
   the trace names after the loop), ``consensus``; busy time under none of
   them is ``unscoped``, so the seven partition the device's busy time;
 * each stretch of device idle time goes to the innermost program span
-  around it on the host (``dppf.batch`` is the chip waiting for input).
+  around it on the host (``dppf.batch`` is the chip waiting for input);
+* ``scope_ms`` times any ``dppf.<name>`` scope by the path alone, so that
+  a reader of a scope that ``layer_of`` does not know needs no change here.
 
 A program without these scopes or spans (an older checkout) gives
 nothing to read, and the readers return None.
@@ -366,6 +368,37 @@ def layer_ms(ctx, layer):
         return None
     per = ctx.traced_rounds * (ctx.tau if layer in STEP_LAYERS else 1)
     return max(r[layer] for r in rows) / per / 1e6
+
+
+def _scope_names(path):
+    """The names of the ``dppf.`` scopes on a scope path (``model`` for
+    ``jvp(dppf.model)``)."""
+    return [m.group(1) for comp in path.split("/")
+            for m in [SCOPE.search(comp)] if m]
+
+
+def scope_ms(ctx, scope, per_step):
+    """Device ms of the leaf ops whose scope path holds ``dppf.<scope>``
+    at any depth, max over chips: a local step's where ``per_step``, else
+    a round's. For a scope that ``layer_of`` knows and that holds no other
+    ``dppf.`` scope, this is its ``layer_ms``; a later scope (an expert
+    layer, a router) needs no change here. None where no op runs under
+    the scope."""
+    sc = _scopes(ctx)
+    if sc is None:
+        return None
+    tr = ctx.trace
+    worst = 0.0
+    for chip in tr.chips:
+        paths = sc.op_paths(chip, tr.window)
+        iv = [(a, b) for a, b, op in trace_mod.leaf_ops(chip.ops)
+              if scope in _scope_names(paths.get(op, ""))]
+        worst = max(worst, trace_mod.measure(
+            trace_mod.clip(trace_mod.union(iv), *tr.window)))
+    if worst <= 0:
+        return None
+    per = ctx.traced_rounds * (ctx.tau if per_step else 1)
+    return worst / per / 1e6
 
 
 def span_idle_ms(ctx, name):

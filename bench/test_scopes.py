@@ -258,3 +258,13 @@ def test_an_op_another_starts_inside_is_not_a_leaf():
     assert row["forward"] == 60 and row["update"] == 0
     assert row["unscoped"] == 40
     assert sum(row.values()) == 100
+
+
+def test_scope_ms_is_the_layer_of_a_leaf_scope(round_probe):
+    """``update`` holds no other ``dppf.`` scope, so its time under the
+    path rule is its layer's; an absent scope reads None."""
+    assert scopes.scope_ms(round_probe, "update", True) == \
+        scopes.layer_ms(round_probe, "update")
+    assert scopes.scope_ms(round_probe, "update", False) == pytest.approx(
+        2 * scopes.layer_ms(round_probe, "update"))
+    assert scopes.scope_ms(round_probe, "experts", True) is None
